@@ -107,4 +107,6 @@ def main() -> None:
 
 
 if __name__ == "__main__":
+    from repro import jax_cache
+    jax_cache.enable()
     main()

@@ -248,6 +248,8 @@ def main() -> None:
 
 
 if __name__ == "__main__":
+    from repro import jax_cache
+    jax_cache.enable()
     main()
 
 

@@ -62,4 +62,6 @@ def main(argv=None):
 
 
 if __name__ == "__main__":
+    from repro import jax_cache
+    jax_cache.enable()
     main()

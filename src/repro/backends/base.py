@@ -188,15 +188,16 @@ class Backend(abc.ABC):
                          for r in range(ctx.shape[0])])
 
     # -- multi-array execution ----------------------------------------------
-    def _make_shard_backend(self) -> "Backend":
-        """A fresh executor for one logical array (subclasses thread their
-        construction kwargs through)."""
+    def _make_shard_backend(self, array: int) -> "Backend":
+        """A fresh executor for logical array ``array`` (subclasses thread
+        their construction kwargs through, and place the array's work on
+        its own device)."""
         return type(self)(self.cfg)
 
     def _shard_backend(self, array: int) -> "Backend":
         be = self._shard_subs.get(array)
         if be is None:
-            be = self._make_shard_backend()
+            be = self._make_shard_backend(array)
             self._shard_subs[array] = be
         return be
 

@@ -38,7 +38,7 @@ class InterpreterBackend(Backend):
         self.max_depth = max_depth
         self.machine = FeatherMachine(cfg, max_depth=max_depth)
 
-    def _make_shard_backend(self) -> "InterpreterBackend":
+    def _make_shard_backend(self, array: int) -> "InterpreterBackend":
         # one functional machine per logical array
         return InterpreterBackend(self.cfg, max_depth=self.max_depth)
 

@@ -65,11 +65,11 @@ from repro.kernels import ops as kernel_ops
 from repro.obs import metrics as obs_metrics
 from repro.obs.trace import trace
 
-#: every pallas_call site increments this (labelled by kernel), so the
-#: scheduler's per-instance ``n_launches`` diffs and the process-wide
-#: scrape agree on what actually launched
+#: every pallas_call site increments this (labelled by kernel and by the
+#: device it ran on), so the scheduler's per-instance ``n_launches``
+#: diffs and the process-wide scrape agree on what actually launched
 _LAUNCHES = obs_metrics.counter(
-    "backend_launches_total", "pallas_call launches by kernel")
+    "backend_launches_total", "pallas_call launches by kernel and device")
 
 if TYPE_CHECKING:  # pragma: no cover
     from repro.configs.feather import FeatherConfig
@@ -237,12 +237,14 @@ class PallasBackend(Backend):
     name = "pallas"
 
     def __init__(self, cfg: "FeatherConfig", *, interpret: bool | None = None,
-                 max_block: int = 2048, compile_cache=None):
+                 max_block: int = 2048, compile_cache=None, device=None):
         super().__init__(cfg)
-        # interpret=None auto-detects: Python-interpret on CPU, Mosaic on TPU
-        self.interpret = (interpret if interpret is not None
-                          else jax.devices()[0].platform != "tpu")
+        # interpret=None: Python-interpret on the CPU backend only
+        self.interpret = kernel_ops.interpret_mode(interpret)
         self.max_block = max_block
+        #: where this backend's launches run (None: JAX's default device);
+        #: a per-array shard backend is pinned to its array's device
+        self.device = device
         self._committed: np.ndarray | None = None
         # id(program) alone would go stale once a Program is collected and
         # its id reused; keeping the Program alongside pins the id and lets
@@ -320,19 +322,16 @@ class PallasBackend(Backend):
         if isinstance(segment, programlib.ShardedFusedSegment):
             return self._run_sharded_segment(segment, tensors)
         comp = self.compile_fused(segment)
-        self.n_launches += 1
-        _LAUNCHES.inc(1, kernel="fused_chain")
+        self._count_launch("fused_chain")
         tensors = tensors or {}
         x = self._resolve("I", tensors, False)
-        ws = [jax.numpy.asarray(
-                  self._resolve(f"W{layer}", tensors, False),
-                  jax.numpy.float32)
+        ws = [self._put(self._resolve(f"W{layer}", tensors, False))
               for layer in range(comp.n_layers)]
         with trace.span("launch", kernel="fused_chain",
                         n_layers=comp.n_layers, bm=comp.bm,
                         out=comp.out_name) as sp:
             out = np.asarray(kernel_ops.fused_chain(
-                jax.numpy.asarray(x, jax.numpy.float32), ws,
+                self._put(x), ws,
                 bm=comp.bm, bks=comp.layer_bks, acts=comp.acts,
                 adapts=comp.adapts, dims=comp.dims,
                 interpret=self.interpret,
@@ -350,15 +349,12 @@ class PallasBackend(Backend):
         request's score+context GEMM pair, each row masked to its own
         true KV length (SNIPPETS §2 flash-decode shape).  Replaces 2*B
         per-request launches with one."""
-        import jax.numpy as jnp
-        self.n_launches += 1
-        _LAUNCHES.inc(1, kernel="flash_decode")
-        k = jnp.asarray(kT, jnp.float32).transpose(0, 2, 1)
+        self._count_launch("flash_decode")
+        k = self._put(kT).transpose(0, 2, 1)
         with trace.span("launch", kernel="flash_decode",
                         batch=int(q.shape[0])) as sp:
             out = np.asarray(kernel_ops.flash_decode(
-                jnp.asarray(q, jnp.float32), k,
-                jnp.asarray(v, jnp.float32),
+                self._put(q), k, self._put(v),
                 lengths, interpret=self.interpret))
             if sp:
                 sp.set(n_launches=self.n_launches)
@@ -370,16 +366,13 @@ class PallasBackend(Backend):
         with the output projection folded into the last KV step, the
         adapt head-merge done as a static in-VMEM permutation.  Replaces
         the attention launch plus B per-request Wo launches."""
-        import jax.numpy as jnp
-        self.n_launches += 1
-        _LAUNCHES.inc(1, kernel="flash_decode_proj")
-        k = jnp.asarray(kT, jnp.float32).transpose(0, 2, 1)
+        self._count_launch("flash_decode_proj")
+        k = self._put(kT).transpose(0, 2, 1)
         with trace.span("launch", kernel="flash_decode_proj",
                         batch=int(q.shape[0])) as sp:
             out = np.asarray(kernel_ops.flash_decode_proj(
-                jnp.asarray(q, jnp.float32), k,
-                jnp.asarray(v, jnp.float32),
-                jnp.asarray(wo, jnp.float32), lengths, m_out=m_out,
+                self._put(q), k, self._put(v),
+                self._put(wo), lengths, m_out=m_out,
                 k_out=k_out, interpret=self.interpret))
             if sp:
                 sp.set(n_launches=self.n_launches)
@@ -398,10 +391,21 @@ class PallasBackend(Backend):
             raise KeyError(f"Load refers to unknown tensor {name!r}")
         return np.asarray(src)
 
-    def _make_shard_backend(self) -> "PallasBackend":
+    def _count_launch(self, kernel: str, device: str | None = None) -> None:
+        self.n_launches += 1
+        _LAUNCHES.inc(1, kernel=kernel,
+                      device=device or str(self.device or "default"))
+
+    def _put(self, x) -> jax.Array:
+        """Host operand -> fp32 device array on this backend's device."""
+        return jax.device_put(np.asarray(x, np.float32), self.device)
+
+    def _make_shard_backend(self, array: int) -> "PallasBackend":
+        devices = jax.devices()
         return PallasBackend(self.cfg, interpret=self.interpret,
                              max_block=self.max_block,
-                             compile_cache=self.compile_cache)
+                             compile_cache=self.compile_cache,
+                             device=devices[array % len(devices)])
 
     def run_sharded(self, sharded, tensors=None):
         """One ``shard_map``-wrapped kernel launch over the array mesh.
@@ -420,7 +424,6 @@ class PallasBackend(Backend):
         if jmesh is None or sharded.n_shards < 2:
             return super().run_sharded(sharded, tensors)
         import jax.numpy as jnp
-        from jax.experimental.shard_map import shard_map
         from jax.sharding import PartitionSpec as P
 
         # every shard shares one mapping choice, so the first shard's
@@ -462,13 +465,12 @@ class PallasBackend(Backend):
                 o = jax.lax.psum(o, ax_name)
             return o
 
-        # check_rep=False: jax has no replication rule for pallas_call
-        self.n_launches += 1
-        _LAUNCHES.inc(1, kernel="nest_gemm_shard_map")
+        # check_vma=False: jax has no varying-axes rule for pallas_call
+        self._count_launch("nest_gemm_shard_map", device="mesh")
         with trace.span("launch", kernel="nest_gemm_shard_map",
                         n_arrays=n, axis=axis, out=sharded.out_name) as sp:
-            out = shard_map(body, mesh=jmesh, in_specs=in_specs,
-                            out_specs=out_spec, check_rep=False)(x, w)
+            out = jax.shard_map(body, mesh=jmesh, in_specs=in_specs,
+                                out_specs=out_spec, check_vma=False)(x, w)
             out = np.ascontiguousarray(np.asarray(out)[:g.m, :g.n])
             if sp:
                 sp.set(n_launches=self.n_launches)
@@ -488,15 +490,13 @@ class PallasBackend(Backend):
         if isinstance(program, programlib.ShardedProgram):
             return self.run_sharded(program, tensors)
         comp = self.compile(program)
-        self.n_launches += 1
-        _LAUNCHES.inc(1, kernel="nest_gemm")
+        self._count_launch("nest_gemm")
         x = self._resolve(comp.input_name, tensors, program.input_elided)
         w = self._resolve(comp.weight_name, tensors, False)
         with trace.span("launch", kernel="nest_gemm", grid=comp.grid,
                         out=comp.out_name) as sp:
             out = np.asarray(kernel_ops.nest_gemm(
-                jax.numpy.asarray(x, jax.numpy.float32),
-                jax.numpy.asarray(w, jax.numpy.float32),
+                self._put(x), self._put(w),
                 bm=comp.bm, bn=comp.bn, bk=comp.bk,
                 interpret=self.interpret, out_dtype=jax.numpy.float32,
                 out_block_t=comp.out_block_t, act=comp.fused_act))

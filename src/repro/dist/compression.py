@@ -11,7 +11,6 @@ from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec as P
 
 
@@ -35,4 +34,5 @@ def compressed_dp_allreduce(grads, mesh):
         return jax.tree.map(
             lambda g: jax.lax.pmean(fake_quantize_int8(g), "data"), tree)
 
-    return shard_map(sync, mesh=mesh, in_specs=P(), out_specs=P())(grads)
+    return jax.shard_map(sync, mesh=mesh, in_specs=P(),
+                         out_specs=P())(grads)

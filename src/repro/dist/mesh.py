@@ -56,7 +56,9 @@ class ArrayMesh:
 
         if len(jax.devices()) < self.n_arrays:
             return None
-        return jax.make_mesh((self.n_arrays,), (self.axis_name,))
+        from jax.sharding import AxisType
+        return jax.make_mesh((self.n_arrays,), (self.axis_name,),
+                             axis_types=(AxisType.Auto,))
 
     def degraded(self, n_down: int = 1) -> "ArrayMesh":
         """The mesh that survives ``n_down`` arrays going unhealthy --

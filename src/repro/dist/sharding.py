@@ -29,7 +29,7 @@ from __future__ import annotations
 import math
 
 import jax
-from jax.sharding import AbstractMesh, NamedSharding, PartitionSpec as P
+from jax.sharding import NamedSharding, PartitionSpec as P
 
 # name -> (priority, candidate mesh axes).  Lower priority wins contended
 # mesh axes; candidates are tried in order; tuple candidates are joint
@@ -106,17 +106,6 @@ def gemm_shard_axis(m: int, k: int, n: int, n_arrays: int,
         if dims[ax] >= n_arrays:
             return ax
     return max(order, key=lambda ax: dims[ax])
-
-
-def abstract_mesh(axis_sizes: tuple[int, ...],
-                  axis_names: tuple[str, ...]) -> AbstractMesh:
-    """Version-portable AbstractMesh constructor (the signature changed
-    from ``(shape_tuple)`` to ``(axis_sizes, axis_names)`` across jax
-    releases)."""
-    try:
-        return AbstractMesh(axis_sizes, axis_names)
-    except TypeError:
-        return AbstractMesh(tuple(zip(axis_names, axis_sizes)))
 
 
 def _mesh_sizes(mesh) -> dict[str, int]:
@@ -208,25 +197,14 @@ def batch_sharding(mesh, batch_spec):
 # In-graph constraints (no-ops outside a mesh context)
 # ---------------------------------------------------------------------------
 
-def _current_mesh():
-    try:
-        from jax.interpreters import pxla
-        m = pxla.thread_resources.env.physical_mesh
-        if m is not None and not m.empty:
-            return m
-    except Exception:  # noqa: BLE001 - mesh plumbing varies across versions
-        pass
-    return None
-
-
 def _constrain(x, spec_fn):
     """Apply with_sharding_constraint(x, spec_fn(sizes)) under the ambient
-    mesh; identity when no mesh is active (single-process smoke tests)."""
-    mesh = _current_mesh()
-    if mesh is None:
+    mesh (``jax.set_mesh``); identity when no mesh is set (single-process
+    smoke tests)."""
+    mesh = jax.sharding.get_abstract_mesh()
+    if mesh.empty:
         return x
-    spec = spec_fn(_mesh_sizes(mesh))
-    return jax.lax.with_sharding_constraint(x, NamedSharding(mesh, spec))
+    return jax.lax.with_sharding_constraint(x, spec_fn(_mesh_sizes(mesh)))
 
 
 def constrain_batch(x, extra: tuple = ()):

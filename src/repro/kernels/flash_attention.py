@@ -8,14 +8,14 @@ recurrence, with causal block skipping via pl.when.
 ``flash_decode`` is the serving twin: one launch advances a whole batch
 of decode requests, each row attending over its *own* gathered K/V pages
 masked to its own true length (grid (requests, KV blocks); per-request
-length rides along as a [B, 1] int32 operand).  Unlike the prefill
+length rides along as a [B, 1] int32 operand, whole in SMEM).  Unlike the prefill
 kernel it applies no ``d**-0.5`` scaling by default -- the MINISA GEMM
 stream's score GEMM carries none, and the batched path must stay on the
 sequential path's numeric trajectory.
 
 ``flash_decode_proj`` is the block-fused variant: at the last KV step
-the finalised context is adapt-cycled (ravel -> tile -> slice ->
-reshape, the runtime's head-merge permutation done statically in VMEM)
+the finalised context is adapt-cycled (the runtime's head-merge
+permutation, ``fused_chain.adapt_block`` in VMEM)
 and multiplied by the resident output projection, so attention + Wo for
 the whole decode batch is ONE launch instead of two.
 """
@@ -28,6 +28,9 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+from repro.kernels.fused_chain import adapt_block
+from repro.kernels.nest_gemm import compiler_params, mxu_dot
 
 NEG_INF = -1e30
 
@@ -128,18 +131,17 @@ def _decode_kernel(q_ref, k_ref, v_ref, len_ref, o_ref, m_ref, l_ref,
     q = q_ref[0]                           # [sq, d]
     k = k_ref[0]                           # [bkv, d]
     v = v_ref[0]
-    s = jnp.dot(q, k.T, preferred_element_type=jnp.float32) * scale
+    s = mxu_dot(q, k.T) * scale
     kpos = ki * bkv + jax.lax.broadcasted_iota(jnp.int32, (sq, bkv), 1)
     # this request's true KV length -- everything past it (other requests'
     # retired pages, zero padding) is masked out of the softmax
-    s = jnp.where(kpos < len_ref[0, 0], s, NEG_INF)
+    s = jnp.where(kpos < len_ref[pl.program_id(0), 0], s, NEG_INF)
     m_prev = m_ref[...]
     m_new = jnp.maximum(m_prev, s.max(axis=-1, keepdims=True))
     p = jnp.where(s <= NEG_INF / 2, 0.0, jnp.exp(s - m_new))
     alpha = jnp.exp(m_prev - m_new)
     l_ref[...] = l_ref[...] * alpha + p.sum(axis=-1, keepdims=True)
-    acc_ref[...] = acc_ref[...] * alpha + jnp.dot(
-        p.astype(v.dtype), v, preferred_element_type=jnp.float32)
+    acc_ref[...] = acc_ref[...] * alpha + mxu_dot(p.astype(v.dtype), v)
     m_ref[...] = m_new
 
     @pl.when(ki == n_kv - 1)
@@ -163,32 +165,25 @@ def _decode_proj_kernel(q_ref, k_ref, v_ref, len_ref, wo_ref, o_ref,
     q = q_ref[0]                           # [sq, d]
     k = k_ref[0]                           # [bkv, d]
     v = v_ref[0]
-    s = jnp.dot(q, k.T, preferred_element_type=jnp.float32) * scale
+    s = mxu_dot(q, k.T) * scale
     kpos = ki * bkv + jax.lax.broadcasted_iota(jnp.int32, (sq, bkv), 1)
-    s = jnp.where(kpos < len_ref[0, 0], s, NEG_INF)
+    s = jnp.where(kpos < len_ref[pl.program_id(0), 0], s, NEG_INF)
     m_prev = m_ref[...]
     m_new = jnp.maximum(m_prev, s.max(axis=-1, keepdims=True))
     p = jnp.where(s <= NEG_INF / 2, 0.0, jnp.exp(s - m_new))
     alpha = jnp.exp(m_prev - m_new)
     l_ref[...] = l_ref[...] * alpha + p.sum(axis=-1, keepdims=True)
-    acc_ref[...] = acc_ref[...] * alpha + jnp.dot(
-        p.astype(v.dtype), v, preferred_element_type=jnp.float32)
+    acc_ref[...] = acc_ref[...] * alpha + mxu_dot(p.astype(v.dtype), v)
     m_ref[...] = m_new
 
     @pl.when(ki == n_kv - 1)
     def _project():
         ctx = acc_ref[...] / jnp.maximum(l_ref[...], 1e-30)   # [sq, d]
-        # runtime adapt on the TRUE context rows: ravel row-major, cycle
-        # to m_out*k_out elements, refold -- the head-merge permutation
+        # runtime adapt on the TRUE context rows (ravel row-major, cycle
+        # to m_out*k_out elements, refold): the head-merge permutation
         # the per-layer path does on the host between pv and wo
-        flat = ctx[:true_sq, :].reshape(-1)
-        need, size = m_out * k_out, true_sq * d
-        if need > size:
-            flat = jnp.tile(flat, -(-need // size))
-        h = flat[:need].reshape(m_out, k_out)
-        o_ref[0] = jnp.dot(h, wo_ref[...],
-                           preferred_element_type=jnp.float32
-                           ).astype(o_ref.dtype)
+        h = adapt_block(ctx, true_sq, d, m_out, k_out, m_out, k_out)
+        o_ref[0] = mxu_dot(h, wo_ref[...]).astype(o_ref.dtype)
 
 
 @functools.partial(jax.jit, static_argnames=(
@@ -222,7 +217,7 @@ def flash_decode_proj(q: jax.Array, k: jax.Array, v: jax.Array,
             pl.BlockSpec((1, sq, d), lambda i, j: (i, 0, 0)),
             pl.BlockSpec((1, bkv, d), lambda i, j: (i, j, 0)),
             pl.BlockSpec((1, bkv, d), lambda i, j: (i, j, 0)),
-            pl.BlockSpec((1, 1), lambda i, j: (i, 0)),
+            pl.BlockSpec(memory_space=pltpu.SMEM),   # [B, 1] lengths
             pl.BlockSpec((k_out, n_out), lambda i, j: (0, 0)),
         ],
         out_specs=pl.BlockSpec((1, m_out, n_out), lambda i, j: (i, 0, 0)),
@@ -232,6 +227,9 @@ def flash_decode_proj(q: jax.Array, k: jax.Array, v: jax.Array,
             pltpu.VMEM((sq, 1), jnp.float32),
             pltpu.VMEM((sq, d), jnp.float32),
         ],
+        compiler_params=compiler_params(
+            4 * (2 * (sq * d + 2 * bkv * d + k_out * n_out + m_out * n_out)
+                 + sq * (d + 2))),
         interpret=interpret,
     )(q, k, v, lengths, wo)
 
@@ -260,7 +258,7 @@ def flash_decode(q: jax.Array, k: jax.Array, v: jax.Array,
             pl.BlockSpec((1, sq, d), lambda i, j: (i, 0, 0)),
             pl.BlockSpec((1, bkv, d), lambda i, j: (i, j, 0)),
             pl.BlockSpec((1, bkv, d), lambda i, j: (i, j, 0)),
-            pl.BlockSpec((1, 1), lambda i, j: (i, 0)),
+            pl.BlockSpec(memory_space=pltpu.SMEM),   # [B, 1] lengths
         ],
         out_specs=pl.BlockSpec((1, sq, d), lambda i, j: (i, 0, 0)),
         out_shape=jax.ShapeDtypeStruct((b, sq, d), q.dtype),

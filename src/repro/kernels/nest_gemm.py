@@ -27,6 +27,27 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+#: Scoped-VMEM ceiling a kernel may ask Mosaic for (v5e has 128 MiB of
+#: VMEM per core; the default scoped limit is far lower).
+VMEM_LIMIT_CAP = 100 << 20
+
+
+def mxu_dot(a, b):
+    """fp32-accumulating matmul at full fp32 contract precision: Mosaic's
+    default precision for fp32 operands may round them to bf16 on the
+    MXU, which the stream oracle's fp32 tolerances do not admit."""
+    return jnp.dot(a, b, precision=jax.lax.Precision.HIGHEST,
+                   preferred_element_type=jnp.float32)
+
+
+def compiler_params(vmem_bytes: int) -> pltpu.CompilerParams:
+    """Scoped-VMEM request for a kernel whose blocks need ``vmem_bytes``
+    (with headroom for Mosaic's internal scratch)."""
+    return pltpu.CompilerParams(
+        vmem_limit_bytes=int(min(VMEM_LIMIT_CAP,
+                                 max(32 << 20, 2 * vmem_bytes))))
+
+
 #: Elementwise activations fusable at the output-store step (the MINISA
 #: Activation instruction's elementwise subset; row-wise functions such as
 #: softmax/norms need full rows and are applied by the caller instead).
@@ -46,9 +67,7 @@ def _nest_gemm_kernel(x_ref, w_ref, o_ref, acc_ref, *, n_k: int,
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    # MXU: low-precision inputs, fp32 accumulate
-    acc_ref[...] += jnp.dot(x_ref[...], w_ref[...],
-                            preferred_element_type=jnp.float32)
+    acc_ref[...] += mxu_dot(x_ref[...], w_ref[...])
 
     @pl.when(k_idx == n_k - 1)
     def _store():
@@ -67,7 +86,8 @@ def nest_gemm(x: jax.Array, w: jax.Array, *, bm: int = 128, bn: int = 128,
               bk: int = 128, interpret: bool = False, out_dtype=None,
               out_block_t: bool = False,
               act: str | None = None) -> jax.Array:
-    """O = X[M, K] @ W[K, N]; shapes must divide by the blocks (ops.py pads).
+    """O = X[M, K] @ W[K, N]; shapes must divide by the blocks, which
+    must be TPU-legal (``ops.nest_gemm`` legalises and pads).
 
     out_block_t=True stores output *tiles* to transposed tile coordinates
     (O_t[j, i] blocks) -- the BIRRD-style free output re-layout: the next
@@ -86,6 +106,9 @@ def nest_gemm(x: jax.Array, w: jax.Array, *, bm: int = 128, bn: int = 128,
     assert act is None or act in ACT_FNS, act
     n_k = k // bk
     out_dtype = out_dtype or x.dtype
+    elem = jnp.dtype(x.dtype).itemsize
+    vmem = (2 * elem * (bm * bk + bk * bn)
+            + 2 * jnp.dtype(out_dtype).itemsize * bm * bn + 4 * bm * bn)
 
     if out_block_t:
         out_spec = pl.BlockSpec((bn, bm), lambda i, j, kk: (j, i))
@@ -105,5 +128,6 @@ def nest_gemm(x: jax.Array, w: jax.Array, *, bm: int = 128, bn: int = 128,
         out_specs=out_spec,
         out_shape=out_shape,
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
+        compiler_params=compiler_params(vmem),
         interpret=interpret,
     )(x, w)

@@ -1,8 +1,15 @@
 """jit'd public wrappers around the Pallas kernels.
 
-On this CPU container the kernels run with interpret=True (the kernel body
-executes in Python, validating semantics); on TPU the same call sites lower
-to Mosaic.  ``interpret=None`` auto-detects.
+``interpret=None`` resolves through :func:`interpret_mode`: the kernel
+bodies run in Pallas interpret mode on the CPU backend only (tests), and
+lower to Mosaic on every other platform -- a TPU that failed to come up
+never silently falls back to interpreting.
+
+Block shapes: callers pass FEATHER+ tile extents (the Program IR's
+tiling); :func:`tpu_block` is the one place that turns them into blocks
+Mosaic accepts -- second-to-last dimension a multiple of 8, last a
+multiple of 128, or the full array dimension -- and the wrappers zero-pad
+operands to the legal block grid (the paper's implicit zero-padding).
 """
 
 from __future__ import annotations
@@ -16,10 +23,29 @@ from repro.kernels import mamba_scan as _ms
 from repro.kernels import nest_gemm as _ng
 
 
-def _auto_interpret(interpret):
+#: Mosaic's (8, 128) tiling of 32-bit arrays: sublane (second-to-last)
+#: and lane (last) block alignment.
+SUBLANE, LANE = 8, 128
+
+
+def interpret_mode(interpret: bool | None = None) -> bool:
+    """Whether Pallas kernels run interpreted: an explicit flag wins,
+    otherwise only on the CPU backend."""
     if interpret is not None:
         return interpret
-    return jax.devices()[0].platform != "tpu"
+    return jax.default_backend() == "cpu"
+
+
+def _round_up(x: int, m: int) -> int:
+    return -(-x // m) * m
+
+
+def tpu_block(tile: int, dim: int, align: int) -> int:
+    """Kernel block along one axis of extent ``dim``: the FEATHER+ tile
+    extent rounded up to ``align``, or the whole axis when that covers
+    it (a full-extent block is legal at any size)."""
+    b = _round_up(max(1, min(tile, dim)), align)
+    return dim if b >= dim else b
 
 
 def _pad_to(x, axis, mult):
@@ -35,11 +61,17 @@ def nest_gemm(x, w, *, bm=128, bn=128, bk=128, interpret=None,
               out_dtype=None, out_block_t=False, act=None):
     """Ragged-shape-safe NEST GEMM (zero-pads to block multiples, the
     paper's implicit zero-padding semantics).  ``act`` fuses an
-    elementwise activation from :data:`nest_gemm.ACT_FNS` into the store."""
-    interpret = _auto_interpret(interpret)
+    elementwise activation from :data:`nest_gemm.ACT_FNS` into the store.
+
+    (bm, bk, bn) are tile extents, legalised by :func:`tpu_block`: K and
+    N are lane axes of an operand; M is a sublane axis, or a lane axis
+    of the block-transposed output."""
+    interpret = interpret_mode(interpret)
     m, k = x.shape
     n = w.shape[1]
-    bm_, bn_, bk_ = (min(bm, _rnd(m)), min(bn, _rnd(n)), min(bk, _rnd(k)))
+    bm_ = tpu_block(bm, m, LANE if out_block_t else SUBLANE)
+    bk_ = tpu_block(bk, k, LANE)
+    bn_ = tpu_block(bn, n, LANE)
     x, _ = _pad_to(x, 0, bm_)
     x, _ = _pad_to(x, 1, bk_)
     w, _ = _pad_to(w, 0, bk_)
@@ -52,7 +84,8 @@ def nest_gemm(x, w, *, bm=128, bn=128, bk=128, interpret=None,
 
 
 def _rnd(x):
-    """Largest power of two <= x (min 8) for block sizing on small shapes."""
+    """Largest power of two <= x (min 8) for block sizing on small shapes
+    (flash attention and the scan, whose blocks are sublane axes)."""
     p = 8
     while p * 2 <= x:
         p *= 2
@@ -74,15 +107,25 @@ def fused_chain(x, ws, *, bm=128, bks=None, acts=None, adapts=None,
     block (attention + MLP, spanning head-split reshapes) runs as one
     launch.
     """
-    interpret = _auto_interpret(interpret)
+    interpret = interpret_mode(interpret)
     n_layers = len(ws)
     if bks is None:
         bks = (128,) * n_layers
     if acts is None:
         acts = (None,) * n_layers
-    bks_ = tuple(max(1, min(bk, w.shape[0])) for bk, w in zip(bks, ws))
+    if dims is None:
+        dims = tuple((x.shape[0], w.shape[0], w.shape[1]) for w in ws)
+    # every layer's K tile is a lane-axis slab read: a multiple of 128
+    # (the kernel zero-pads each weight's K to its tile grid)
+    bks_ = tuple(_round_up(max(1, min(bk, d[1])), LANE)
+                 for bk, d in zip(bks, dims))
+    if adapts is not None and any(adapts):
+        # the in-kernel adapt needs every row of every layer resident
+        bm_ = _round_up(max(d[0] for d in dims), SUBLANE)
+    else:
+        bm_ = _round_up(max(1, min(bm, dims[0][0])), SUBLANE)
     return _fc.fused_chain(
-        x, *ws, bm=bm, bks=bks_, acts=tuple(acts),
+        x, *ws, bm=bm_, bks=bks_, acts=tuple(acts),
         adapts=None if adapts is None else tuple(adapts),
         dims=None if dims is None else tuple(tuple(d) for d in dims),
         interpret=interpret, out_dtype=out_dtype)
@@ -91,7 +134,7 @@ def fused_chain(x, ws, *, bm=128, bks=None, acts=None, adapts=None,
 def flash_attention(q, k, v, *, causal=True, bq=128, bkv=128,
                     interpret=None):
     """q, k, v: [B, S, H, D] -> [B, S, H, D]."""
-    interpret = _auto_interpret(interpret)
+    interpret = interpret_mode(interpret)
     b, s, h, d = q.shape
     sk = k.shape[1]
     bq_, bkv_ = min(bq, _rnd(s)), min(bkv, _rnd(sk))
@@ -117,7 +160,7 @@ def flash_decode(q, k, v, lengths=None, *, bkv=128, interpret=None,
     q: [B, sq, d], k, v: [B, skv, d], lengths: [B] or [B, 1] int true
     lengths (defaults to the full skv for every request).
     """
-    interpret = _auto_interpret(interpret)
+    interpret = interpret_mode(interpret)
     b, sq, d = q.shape
     sk = k.shape[1]
     if lengths is None:
@@ -146,7 +189,7 @@ def flash_decode_proj(q, k, v, wo, lengths=None, *, m_out, k_out,
     runtime ``adapt`` head-merge) before the projection.  Returns
     [B, m_out, n_out].
     """
-    interpret = _auto_interpret(interpret)
+    interpret = interpret_mode(interpret)
     b, sq, d = q.shape
     sk = k.shape[1]
     if lengths is None:
@@ -164,7 +207,7 @@ def flash_decode_proj(q, k, v, wo, lengths=None, *, m_out, k_out,
 
 
 def mamba_scan(da, dbx, c, h0, *, d_blk=256, chunk=64, interpret=None):
-    interpret = _auto_interpret(interpret)
+    interpret = interpret_mode(interpret)
     b, l, d, n = da.shape
     d_blk = min(d_blk, _rnd(d))
     chunk = min(chunk, _rnd(l))

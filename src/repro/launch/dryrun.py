@@ -88,7 +88,7 @@ def lower_cell(arch: str, shape_name: str, mesh, verbose: bool = True):
                 "SKIP(full-attention)"}
     model = build_model(cfg)
     t0 = time.time()
-    with mesh:
+    with jax.set_mesh(mesh):
         if shape.kind == "train":
             tstep = make_train_step(model, TrainConfig())
             batch_spec = model.batch_spec(shape)

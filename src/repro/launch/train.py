@@ -69,7 +69,7 @@ def main(argv=None):
     batch0 = data.batch(0)
     batch_spec = jax.tree.map(
         lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype), batch0)
-    with mesh:
+    with jax.set_mesh(mesh):
         (p_sh, o_sh, b_sh), _ = shardings_for(model, mesh, batch_spec)
         jit_step = jax.jit(step_fn, in_shardings=(p_sh, o_sh, b_sh),
                            out_shardings=(p_sh, o_sh, None),

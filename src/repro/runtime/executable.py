@@ -501,8 +501,9 @@ class ModelExecutable:
         be = backend if not isinstance(backend, str) \
             else self.make_backend(backend)
         env = dict(tensors) if tensors else {}
-        for name, arr in self.make_tensors(seed).items():
-            env.setdefault(name, arr)
+        if any(name not in env for name in self.tensor_specs()):
+            for name, arr in self.make_tensors(seed).items():
+                env.setdefault(name, arr)
 
         prev: np.ndarray | None = None
         ref_prev: np.ndarray | None = None

@@ -180,3 +180,44 @@ def test_mamba_scan_matches_model_recurrence():
                                rtol=1e-4, atol=1e-4)
     np.testing.assert_allclose(np.asarray(h_kernel), np.asarray(h_last),
                                rtol=1e-4, atol=1e-4)
+
+
+# ---------------------------------------------------------------------------
+# TPU legality of the launch geometry (the block legaliser, the in-kernel
+# adapt, the interpret decision)
+# ---------------------------------------------------------------------------
+
+@settings(max_examples=40, deadline=None)
+@given(tile=st.integers(1, 3000), dim=st.integers(1, 3000),
+       align=st.sampled_from([8, 128]))
+def test_tpu_block_is_mosaic_legal(tile, dim, align):
+    """A legal block is the whole axis or an ``align`` multiple no
+    smaller than the tile it came from (clipped to the axis)."""
+    b = ops.tpu_block(tile, dim, align)
+    assert b == dim or (b % align == 0 and min(tile, dim) <= b < dim)
+
+
+@pytest.mark.parametrize("m_l,n_l,m_next,k_next", [
+    (4, 32, 4, 16), (4, 16, 4, 32), (3, 5, 7, 4), (1, 1024, 1, 128),
+    (256, 1024, 256, 128), (256, 128, 256, 3072), (5, 7, 3, 11),
+    (2, 6, 9, 4)])
+def test_adapt_block_matches_runtime_adapt(m_l, n_l, m_next, k_next):
+    """The in-kernel adapt (one-hot matmuls, no reshape) is bit-identical
+    to the runtime's host glue, with zeros outside the adapted region."""
+    from repro.kernels.fused_chain import adapt_block
+    from repro.runtime.executable import adapt
+    rows, width = -(-m_next // 8) * 8, -(-k_next // 128) * 128
+    src = RNG.standard_normal((-(-m_l // 8) * 8,
+                               -(-n_l // 128) * 128)).astype(np.float32)
+    out = np.asarray(adapt_block(jnp.asarray(src), m_l, n_l, m_next, k_next,
+                                 rows, width))
+    np.testing.assert_array_equal(out[:m_next, :k_next],
+                                  adapt(src[:m_l, :n_l], m_next, k_next))
+    assert not out[m_next:].any() and not out[:, k_next:].any()
+
+
+def test_interpret_mode_only_on_cpu():
+    import jax
+    assert ops.interpret_mode(True) is True
+    assert ops.interpret_mode(False) is False
+    assert ops.interpret_mode() == (jax.default_backend() == "cpu")

@@ -1,6 +1,8 @@
 """Launch-layer tests: input_specs, elastic resume (re-shard restore),
 multimodal serving, trainer resume via the CLI driver."""
 
+import pathlib
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -10,6 +12,8 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 from repro.configs.base import SHAPES, reduced
 from repro.configs.registry import ARCH_IDS, get_config
 from repro.models import build_model
+
+REPO_ROOT = pathlib.Path(__file__).resolve().parents[1]
 
 
 # ---------------------------------------------------------------------------
@@ -133,7 +137,7 @@ def test_train_cli_resume(tmp_path):
     def run(extra):
         return subprocess.run(
             [sys.executable, "-m", "repro.launch.train"] + args + extra,
-            capture_output=True, text=True, env=env, cwd="/root/repo",
+            capture_output=True, text=True, env=env, cwd=REPO_ROOT,
             timeout=240)
 
     r1 = run(["--steps", "4"])

@@ -336,6 +336,29 @@ def test_pallas_shard_map_path_when_devices_available():
                                        err_msg=f"{df} axis={axis}")
 
 
+def test_pallas_shard_backends_pinned_to_their_devices():
+    """Each logical array's executor runs on its own device (wrapping
+    when the host has fewer devices than arrays), and its launches are
+    counted under that device."""
+    import jax
+    from repro.obs import metrics as obs_metrics
+    devices = jax.devices()
+    be = backends.get_backend("pallas", CFG)
+    g = mapper.Gemm(m=24, k=16, n=20)
+    prog = program.lower(g, _choice(), CFG)
+    t = _tensors(g)
+    for a in range(4):
+        sub = be._shard_backend(a)
+        assert sub.device == devices[a % len(devices)]
+        before = obs_metrics.snapshot().get("backend_launches_total", {})
+        out = sub.run_program(prog, t)[prog.out_name]
+        after = obs_metrics.snapshot()["backend_launches_total"]
+        key = f'{{device="{sub.device}",kernel="nest_gemm"}}'
+        assert after[key] == before.get(key, 0) + 1
+        np.testing.assert_allclose(out, t["I"] @ t["W"], rtol=2e-4,
+                                   atol=2e-4 + 2e-4 * g.k)
+
+
 def test_array_mesh_validation():
     with pytest.raises(ValueError):
         ArrayMesh(0)

@@ -8,7 +8,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-from jax.sharding import PartitionSpec as P
+from jax.sharding import AbstractMesh, PartitionSpec as P
 
 from repro.ckpt.manager import CheckpointManager
 from repro.configs.base import ShapeConfig, reduced
@@ -117,8 +117,8 @@ def test_data_deterministic_per_step():
 # Sharding rules (AbstractMesh: no devices needed)
 # ---------------------------------------------------------------------------
 
-MESH = shlib.abstract_mesh((16, 16), ("data", "model"))
-POD_MESH = shlib.abstract_mesh((2, 16, 16), ("pod", "data", "model"))
+MESH = AbstractMesh((16, 16), ("data", "model"))
+POD_MESH = AbstractMesh((2, 16, 16), ("pod", "data", "model"))
 
 
 def test_spec_tp_and_fsdp():
