@@ -31,6 +31,7 @@ JAX device mesh when available).
 from __future__ import annotations
 
 import abc
+import contextlib
 from typing import TYPE_CHECKING
 
 import numpy as np
@@ -54,6 +55,12 @@ class Backend(abc.ABC):
         #: kernel launches performed (only compiled backends bump this;
         #: the interpreter replays instructions, it does not launch)
         self.n_launches = 0
+        #: bytes copied host->device and device->host (compiled backends
+        #: only; the interpreter never leaves the host)
+        self.n_h2d_bytes = 0
+        self.n_d2h_bytes = 0
+        #: what ``run_program``'s 'W' operand holds (see weight_kind)
+        self._weight_kind = "weight"
         # one executor per logical array, created on first sharded run
         self._shard_subs: dict[int, "Backend"] = {}
 
@@ -71,6 +78,18 @@ class Backend(abc.ABC):
 
         A :class:`ShardedProgram` argument dispatches to
         :meth:`run_sharded`."""
+
+    @contextlib.contextmanager
+    def weight_kind(self, kind: str):
+        """Within the block, ``run_program``'s 'W' operand holds ``kind``:
+        ``kv`` for a dynamic step's K^T / V, ``weight`` otherwise.  The
+        executable knows its tensors' kinds; a compiled backend labels
+        its uploads with them."""
+        prev, self._weight_kind = self._weight_kind, kind
+        try:
+            yield
+        finally:
+            self._weight_kind = prev
 
     # -- chained-segment execution -------------------------------------------
     def run_segment(self, segment, tensors: dict[str, np.ndarray] | None
@@ -129,7 +148,8 @@ class Backend(abc.ABC):
             t = {k: v for k, v in tensors.items() if k != "I"}
             if "I" in tensors:
                 t["I"] = np.asarray(tensors["I"])[m0:m1]
-            res = sub.run_segment(fseg, t)
+            with self._fold_bytes(sub):
+                res = sub.run_segment(fseg, t)
             out[m0:m1] = np.asarray(res[fseg.out_name])[:m1 - m0]
             self.n_launches += sub.n_launches - before
         self.outputs[segment.out_name] = out
@@ -161,10 +181,11 @@ class Backend(abc.ABC):
                 ("base run_batched_attention replays full-width Programs; "
                  f"ragged lengths {lengths} need the Pallas backend")
         outs = []
-        for r in range(q.shape[0]):
-            self.run_program(qk, {"I": q[r], "W": kT[r]})
-            out = self.run_program(pv, {"W": v[r]})[pv.out_name]
-            outs.append(np.asarray(out))
+        with self.weight_kind("kv"):
+            for r in range(q.shape[0]):
+                self.run_program(qk, {"I": q[r], "W": kT[r]})
+                out = self.run_program(pv, {"W": v[r]})[pv.out_name]
+                outs.append(np.asarray(out))
         return np.stack(outs)
 
     def run_batched_attention_proj(self, programs, q: np.ndarray,
@@ -194,6 +215,16 @@ class Backend(abc.ABC):
         its own device)."""
         return type(self)(self.cfg)
 
+    @contextlib.contextmanager
+    def _fold_bytes(self, sub: "Backend"):
+        """Count the bytes a shard executor copies as this backend's."""
+        h2d, d2h = sub.n_h2d_bytes, sub.n_d2h_bytes
+        try:
+            yield
+        finally:
+            self.n_h2d_bytes += sub.n_h2d_bytes - h2d
+            self.n_d2h_bytes += sub.n_d2h_bytes - d2h
+
     def _shard_backend(self, array: int) -> "Backend":
         be = self._shard_subs.get(array)
         if be is None:
@@ -215,10 +246,11 @@ class Backend(abc.ABC):
         acc = np.zeros((g.m, g.n), np.float32)
         for shard in sharded.shards:
             sub = self._shard_backend(shard.array)
-            out = np.asarray(
-                sub.run_program(shard.program,
-                                shard.slice_tensors(tensors))
-                [sharded.out_name])
+            with self._fold_bytes(sub), sub.weight_kind(self._weight_kind):
+                out = np.asarray(
+                    sub.run_program(shard.program,
+                                    shard.slice_tensors(tensors))
+                    [sharded.out_name])
             if sharded.reduce:
                 acc += out
             else:

@@ -325,22 +325,20 @@ class PallasBackend(Backend):
         self._count_launch("fused_chain")
         tensors = tensors or {}
         x = self._resolve("I", tensors, False)
-        ws = [self._put(self._resolve(f"W{layer}", tensors, False))
+        ws = [self._resolve(f"W{layer}", tensors, False)
               for layer in range(comp.n_layers)]
         with trace.span("launch", kernel="fused_chain",
                         n_layers=comp.n_layers, bm=comp.bm,
                         out=comp.out_name) as sp:
-            out = np.asarray(kernel_ops.fused_chain(
-                self._put(x), ws,
-                bm=comp.bm, bks=comp.layer_bks, acts=comp.acts,
+            xd = self._put(x, "input")
+            wds = [self._put(w, "weight") for w in ws]
+            out = self._finish("fused_chain", lambda: kernel_ops.fused_chain(
+                xd, wds, bm=comp.bm, bks=comp.layer_bks, acts=comp.acts,
                 adapts=comp.adapts, dims=comp.dims,
-                interpret=self.interpret,
-                out_dtype=jax.numpy.float32))
-            if sp:          # np.asarray already forced device sync
-                sp.set(n_launches=self.n_launches,
-                       vmem_highwater_bytes=getattr(
-                           segment, "vmem_highwater_bytes",
-                           lambda: None)())
+                interpret=self.interpret, out_dtype=jax.numpy.float32))
+            if sp:
+                sp.set(vmem_highwater_bytes=getattr(
+                    segment, "vmem_highwater_bytes", lambda: None)())
         self.outputs[comp.out_name] = out
         return self.outputs
 
@@ -350,15 +348,13 @@ class PallasBackend(Backend):
         true KV length (SNIPPETS §2 flash-decode shape).  Replaces 2*B
         per-request launches with one."""
         self._count_launch("flash_decode")
-        k = self._put(kT).transpose(0, 2, 1)
         with trace.span("launch", kernel="flash_decode",
-                        batch=int(q.shape[0])) as sp:
-            out = np.asarray(kernel_ops.flash_decode(
-                self._put(q), k, self._put(v),
-                lengths, interpret=self.interpret))
-            if sp:
-                sp.set(n_launches=self.n_launches)
-        return out
+                        batch=int(q.shape[0])):
+            k = self._put(kT, "kv").transpose(0, 2, 1)
+            qd, vd = self._put(q, "input"), self._put(v, "kv")
+            return self._finish(
+                "flash_decode", lambda: kernel_ops.flash_decode(
+                    qd, k, vd, lengths, interpret=self.interpret))
 
     def run_batched_attention_proj(self, programs, q, kT, v, wo, *,
                                    m_out, k_out, lengths=None):
@@ -367,16 +363,15 @@ class PallasBackend(Backend):
         adapt head-merge done as a static in-VMEM permutation.  Replaces
         the attention launch plus B per-request Wo launches."""
         self._count_launch("flash_decode_proj")
-        k = self._put(kT).transpose(0, 2, 1)
         with trace.span("launch", kernel="flash_decode_proj",
-                        batch=int(q.shape[0])) as sp:
-            out = np.asarray(kernel_ops.flash_decode_proj(
-                self._put(q), k, self._put(v),
-                self._put(wo), lengths, m_out=m_out,
-                k_out=k_out, interpret=self.interpret))
-            if sp:
-                sp.set(n_launches=self.n_launches)
-        return out
+                        batch=int(q.shape[0])):
+            k = self._put(kT, "kv").transpose(0, 2, 1)
+            qd, vd = self._put(q, "input"), self._put(v, "kv")
+            wod = self._put(wo, "weight")
+            return self._finish(
+                "flash_decode_proj", lambda: kernel_ops.flash_decode_proj(
+                    qd, k, vd, wod, lengths, m_out=m_out, k_out=k_out,
+                    interpret=self.interpret))
 
     def _resolve(self, name: str | None, tensors, elided: bool):
         if name is None:
@@ -396,9 +391,35 @@ class PallasBackend(Backend):
         _LAUNCHES.inc(1, kernel=kernel,
                       device=device or str(self.device or "default"))
 
-    def _put(self, x) -> jax.Array:
-        """Host operand -> fp32 device array on this backend's device."""
-        return jax.device_put(np.asarray(x, np.float32), self.device)
+    def _put(self, x, operand: str) -> jax.Array:
+        """Host operand -> fp32 device array on this backend's device.
+
+        Under tracing the ``backend.put`` span waits for the transfer,
+        so it times the upload; ``operand`` is ``input``, ``weight`` or
+        ``kv``."""
+        host = np.asarray(x, np.float32)
+        self.n_h2d_bytes += host.nbytes
+        with trace.span("backend.put") as sp:
+            arr = jax.device_put(host, self.device)
+            if sp:
+                sp.set(bytes=host.nbytes, operand=operand)
+                arr.block_until_ready()
+        return arr
+
+    def _finish(self, kernel: str, dispatch) -> np.ndarray:
+        """Run ``dispatch`` (one kernel launch) and copy its output to
+        the host.  Under tracing ``backend.wait`` ends when the device
+        is done and ``backend.fetch`` times the device-to-host copy."""
+        with trace.span("backend.wait", kernel=kernel) as sp:
+            y = dispatch()
+            if sp:
+                y.block_until_ready()
+        with trace.span("backend.fetch") as sp:
+            out = np.asarray(y)
+            if sp:
+                sp.set(bytes=out.nbytes)
+        self.n_d2h_bytes += out.nbytes
+        return out
 
     def _make_shard_backend(self, array: int) -> "PallasBackend":
         devices = jax.devices()
@@ -433,24 +454,22 @@ class PallasBackend(Backend):
         g = sharded.base.gemm
         x = self._resolve(comp.input_name or "I", tensors, False)
         w = self._resolve(comp.weight_name, tensors, False)
-        x = jnp.asarray(x, jnp.float32)
-        w = jnp.asarray(w, jnp.float32)
         n = sharded.mesh.n_arrays
         axis, ax_name = sharded.axis, sharded.mesh.axis_name
         dim = {"m": g.m, "n": g.n, "k": g.k}[axis]
         pad = -dim % (-(-dim // n) * n)
 
         if axis == "m":
-            x = jnp.pad(x, ((0, pad), (0, 0)))
+            x = np.pad(x, ((0, pad), (0, 0)))
             in_specs = (P(ax_name, None), P(None, None))
             out_spec = P(ax_name, None)
         elif axis == "n":
-            w = jnp.pad(w, ((0, 0), (0, pad)))
+            w = np.pad(w, ((0, 0), (0, pad)))
             in_specs = (P(None, None), P(None, ax_name))
             out_spec = P(None, ax_name)
         else:
-            x = jnp.pad(x, ((0, 0), (0, pad)))
-            w = jnp.pad(w, ((0, pad), (0, 0)))
+            x = np.pad(x, ((0, 0), (0, pad)))
+            w = np.pad(w, ((0, pad), (0, 0)))
             in_specs = (P(None, ax_name), P(ax_name, None))
             out_spec = P()
 
@@ -468,12 +487,12 @@ class PallasBackend(Backend):
         # check_vma=False: jax has no varying-axes rule for pallas_call
         self._count_launch("nest_gemm_shard_map", device="mesh")
         with trace.span("launch", kernel="nest_gemm_shard_map",
-                        n_arrays=n, axis=axis, out=sharded.out_name) as sp:
-            out = jax.shard_map(body, mesh=jmesh, in_specs=in_specs,
-                                out_specs=out_spec, check_vma=False)(x, w)
-            out = np.ascontiguousarray(np.asarray(out)[:g.m, :g.n])
-            if sp:
-                sp.set(n_launches=self.n_launches)
+                        n_arrays=n, axis=axis, out=sharded.out_name):
+            xd, wd = self._put(x, "input"), self._put(w, self._weight_kind)
+            out = self._finish("nest_gemm_shard_map", lambda: jax.shard_map(
+                body, mesh=jmesh, in_specs=in_specs, out_specs=out_spec,
+                check_vma=False)(xd, wd))
+            out = np.ascontiguousarray(out[:g.m, :g.n])
         if comp.host_act is not None:
             # per-shard Programs only keep shard-local activations (see
             # shard_program), so host application on the assembled output
@@ -494,22 +513,21 @@ class PallasBackend(Backend):
         x = self._resolve(comp.input_name, tensors, program.input_elided)
         w = self._resolve(comp.weight_name, tensors, False)
         with trace.span("launch", kernel="nest_gemm", grid=comp.grid,
-                        out=comp.out_name) as sp:
-            out = np.asarray(kernel_ops.nest_gemm(
-                self._put(x), self._put(w),
-                bm=comp.bm, bn=comp.bn, bk=comp.bk,
+                        out=comp.out_name):
+            xd, wd = self._put(x, "input"), self._put(w, self._weight_kind)
+            out = self._finish("nest_gemm", lambda: kernel_ops.nest_gemm(
+                xd, wd, bm=comp.bm, bn=comp.bn, bk=comp.bk,
                 interpret=self.interpret, out_dtype=jax.numpy.float32,
                 out_block_t=comp.out_block_t, act=comp.fused_act))
-            if sp:
-                sp.set(n_launches=self.n_launches)
-        if comp.out_block_t:
-            # the kernel stored the IO-S (search-oriented) accumulator; the
-            # final Write's host-facing view is its transpose
-            if comp.host_act is not None:
+        if comp.host_act is not None:
+            # under IO-S in the kernel's (search-oriented) orientation,
+            # as the interpreter applies it
+            with trace.span("host.act"):
                 out = np.asarray(comp.host_act(out))
+        if comp.out_block_t:
+            # the kernel stored the IO-S accumulator; the final Write's
+            # host-facing view is its transpose
             out = np.ascontiguousarray(out.T)
-        elif comp.host_act is not None:
-            out = np.asarray(comp.host_act(out))
         self.outputs[comp.out_name] = out
         if comp.commit:
             self._committed = out

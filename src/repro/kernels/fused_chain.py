@@ -55,7 +55,6 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.kernels.nest_gemm import ACT_FNS, compiler_params, mxu_dot
-from repro.obs.trace import trace
 
 
 _NEG = -1e30
@@ -244,29 +243,6 @@ def _pad_axis(x, axis, target):
     widths = [(0, 0)] * x.ndim
     widths[axis] = (0, pad)
     return jnp.pad(x, widths)
-
-
-def fused_chain(x: jax.Array, *ws: jax.Array, bm: int,
-                bks: tuple[int, ...], acts: tuple[str | None, ...],
-                adapts: tuple[bool, ...] | None = None,
-                dims: tuple[tuple[int, int, int], ...] | None = None,
-                interpret: bool = False, out_dtype=None) -> jax.Array:
-    """Traced entry point for the jitted megakernel: when the ``obs``
-    tracer is enabled, the launch is timed to ``block_until_ready`` (the
-    device-sync wall clock of the ONE ``pallas_call``); disabled, this
-    is one attribute check on top of the jit dispatch."""
-    if not trace.enabled:
-        return _fused_chain_jit(x, *ws, bm=bm, bks=bks, acts=acts,
-                                adapts=adapts, dims=dims,
-                                interpret=interpret, out_dtype=out_dtype)
-    with trace.span("kernel.fused_chain", n_layers=len(ws), bm=bm,
-                    bks=tuple(bks), grid_k=sum(
-                        -(-d[1] // max(1, min(bk, d[1])))
-                        for d, bk in zip(dims, bks)) if dims else None):
-        return jax.block_until_ready(
-            _fused_chain_jit(x, *ws, bm=bm, bks=bks, acts=acts,
-                             adapts=adapts, dims=dims,
-                             interpret=interpret, out_dtype=out_dtype))
 
 
 @functools.partial(jax.jit,

@@ -124,7 +124,7 @@ def fused_chain(x, ws, *, bm=128, bks=None, acts=None, adapts=None,
         bm_ = _round_up(max(d[0] for d in dims), SUBLANE)
     else:
         bm_ = _round_up(max(1, min(bm, dims[0][0])), SUBLANE)
-    return _fc.fused_chain(
+    return _fc._fused_chain_jit(
         x, *ws, bm=bm_, bks=bks_, acts=tuple(acts),
         adapts=None if adapts is None else tuple(adapts),
         dims=None if dims is None else tuple(tuple(d) for d in dims),

@@ -15,7 +15,8 @@ a way to see inside a serving tick.  This tracer is that substrate:
     the untraced hot path (``tests/test_obs.py`` bounds it against a
     decode tick);
   * **nestable + thread-safe**: spans keep a per-thread stack (depth and
-    track inherit from the enclosing span) and finished events append
+    track inherit from the enclosing span, and every event carries its
+    enclosing span's ``id`` as ``parent``) and finished events append
     under one lock with a global sequence number, so the event order is
     deterministic for a deterministic workload;
   * **tracks** give every span a swimlane identity: ``("host", <thread>)``
@@ -41,6 +42,7 @@ Usage::
 from __future__ import annotations
 
 import dataclasses
+import itertools
 import threading
 import time
 
@@ -57,6 +59,8 @@ class SpanEvent:
     seq: int                          # global completion order
     attrs: dict
     instant: bool = False
+    id: int = 0                       # unique within the tracer's buffer
+    parent: int | None = None         # ``id`` of the enclosing span
 
     @property
     def t1_s(self) -> float:
@@ -92,7 +96,8 @@ NULL_SPAN = _NullSpan()
 class _Span:
     """A live span; records a SpanEvent on ``__exit__``."""
 
-    __slots__ = ("_tracer", "name", "track", "attrs", "_t0", "_depth")
+    __slots__ = ("_tracer", "name", "track", "attrs", "_t0", "_depth",
+                 "id", "parent")
 
     def __init__(self, tracer: "Tracer", name: str, track: tuple | None,
                  attrs: dict):
@@ -116,6 +121,8 @@ class _Span:
             self.track = (stack[-1].track if stack
                           else ("host", threading.current_thread().name))
         self._depth = len(stack)
+        self.id = next(tracer._ids)
+        self.parent = stack[-1].id if stack else None
         stack.append(self)
         self._t0 = time.perf_counter()
         return self
@@ -129,7 +136,8 @@ class _Span:
         tracer._record(SpanEvent(
             name=self.name, track=self.track,
             t0_s=self._t0 - tracer._origin, dur_s=t1 - self._t0,
-            depth=self._depth, seq=0, attrs=self.attrs))
+            depth=self._depth, seq=0, attrs=self.attrs, id=self.id,
+            parent=self.parent))
         return False
 
 
@@ -143,6 +151,7 @@ class Tracer:
         self._events: list[SpanEvent] = []
         self._seq = 0
         self._origin = time.perf_counter()
+        self._ids = itertools.count(1)
         self._tls = threading.local()
 
     # -- lifecycle ----------------------------------------------------------
@@ -159,6 +168,7 @@ class Tracer:
             self._events = []
             self._seq = 0
             self._origin = time.perf_counter()
+            self._ids = itertools.count(1)
         return self
 
     def __enter__(self) -> "Tracer":          # `with trace:` == enable
@@ -200,19 +210,24 @@ class Tracer:
         self._record(SpanEvent(
             name=name, track=track,
             t0_s=time.perf_counter() - self._origin, dur_s=0.0,
-            depth=len(stack), seq=0, attrs=attrs, instant=True))
+            depth=len(stack), seq=0, attrs=attrs, instant=True,
+            id=next(self._ids), parent=stack[-1].id if stack else None))
 
     def record(self, name: str, track: tuple, t0: float, t1: float,
                depth: int = 0, **attrs) -> None:
         """Inject a span with explicit ``perf_counter`` endpoints -- used
         where one collective measurement covers several lanes (a batched
         decode launch recorded onto every participating request's
-        swimlane)."""
+        swimlane).  Its parent is the innermost live span of this
+        thread that began before ``t0``."""
         if not self.enabled:
             return
+        parent = next((sp.id for sp in reversed(self._stack())
+                       if sp._t0 <= t0), None)
         self._record(SpanEvent(
             name=name, track=track, t0_s=t0 - self._origin,
-            dur_s=max(0.0, t1 - t0), depth=depth, seq=0, attrs=attrs))
+            dur_s=max(0.0, t1 - t0), depth=depth, seq=0, attrs=attrs,
+            id=next(self._ids), parent=parent))
 
     # -- consumption --------------------------------------------------------
     def events(self) -> list[SpanEvent]:

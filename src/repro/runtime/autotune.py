@@ -4,9 +4,10 @@ The joint segment search (``mapper.search_segment``, memoised in the
 ProgramCache frontier tier) prices geometries analytically; this pass
 closes the loop against *measured* hardware the way the configurable-
 stack papers do: compile the top-k frontier points through the existing
-``PallasBackend`` and score them with the PR 8 ``obs`` telemetry spine
--- the per-launch spans already carry ``block_until_ready`` wall clock
-and VMEM high-water, and ``obs.export.span_breakdown`` turns them into
+``PallasBackend`` and score them with the ``obs`` telemetry spine --
+under tracing each ``launch`` span holds the launch's uploads, the
+kernel wait and the output copy, each timed to the device, and carries
+the VMEM high-water; ``obs.export.span_breakdown`` turns them into
 kernel-vs-host fractions -- no parallel timing path.
 
 The measured winner persists in the ProgramCache tuned tier under a key
@@ -92,10 +93,10 @@ def _segment_tensors(programs, seed: int = 0) -> dict:
 
 def _measure_launches(backend, seg, tensors, iters: int) -> dict | None:
     """Run the fused segment ``iters`` times and read the result off the
-    telemetry spine: the backend's ``launch`` spans are timed to
-    ``block_until_ready`` (the np.asarray device sync) and carry the
-    VMEM high-water; ``span_breakdown`` gives the kernel-vs-host split
-    of the measured window."""
+    telemetry spine: the backend's ``launch`` spans end after the
+    output's copy to the host (uploads and the kernel are waited for
+    under tracing) and carry the VMEM high-water; ``span_breakdown``
+    gives the kernel-vs-host split of the measured window."""
     backend.run_segment(seg, tensors)        # compile + jit warm-up
     was_enabled = trace.enabled
     events_before = len(trace.events())

@@ -151,6 +151,13 @@ class Step:
     def input_name(self) -> str:
         return f"I{self.index}"
 
+    @property
+    def weight_kind(self) -> str:
+        """What the 'W' operand holds: ``kv`` for a dynamic step (kind
+        ``dynamic`` in :meth:`ModelExecutable.tensor_specs`), else
+        ``weight``."""
+        return "kv" if self.op.dynamic else "weight"
+
 
 @dataclasses.dataclass
 class Segment:
@@ -532,7 +539,8 @@ class ModelExecutable:
                     out = np.asarray(
                         be.run_segment(seg.fused, t)[seg.fused.out_name])
                 if last.host_act is not None:
-                    out = np.asarray(last.host_act(out))
+                    with trace.span("host.act"):
+                        out = np.asarray(last.host_act(out))
                 if check:
                     ref = np.asarray(seg_input(first, ref_prev, env),
                                      np.float32)
@@ -566,13 +574,15 @@ class ModelExecutable:
                     # sharded streams do not chain on-chip: the producer's
                     # output crosses the host boundary explicitly
                     t["I"] = prev
-                with trace.span("segment", kind="per_step", step=s.index):
+                with trace.span("segment", kind="per_step", step=s.index), \
+                        be.weight_kind(s.weight_kind):
                     out = np.asarray(
                         be.run_program(s.sharded if s.sharded is not None
                                        else s.program, t)
                         [s.program.out_name])
                 if s.host_act is not None:
-                    out = np.asarray(s.host_act(out))
+                    with trace.span("host.act"):
+                        out = np.asarray(s.host_act(out))
                 if check:
                     if s.input_mode == "fresh":
                         ref_x = env[s.input_name]
@@ -712,36 +722,42 @@ class ModelExecutable:
                         prevs[r] = self._run_steps_perreq(
                             be, steps, envs[r], prevs[r])
                 continue
-            xs = []
-            for r in range(n):
-                if first.input_mode == "fresh":
-                    xs.append(np.asarray(envs[r][first.input_name],
-                                         np.float32))
-                elif first.input_mode == "adapt":
-                    xs.append(adapt(prevs[r], g.m, g.k))
-                else:          # 'wired' never starts a segment
-                    xs.append(np.asarray(prevs[r], np.float32))
+            with trace.span("host.stage", kind=bseg.kind):
+                xs = []
+                for r in range(n):
+                    if first.input_mode == "fresh":
+                        xs.append(np.asarray(envs[r][first.input_name],
+                                             np.float32))
+                    elif first.input_mode == "adapt":
+                        xs.append(adapt(prevs[r], g.m, g.k))
+                    else:          # 'wired' never starts a segment
+                        xs.append(np.asarray(prevs[r], np.float32))
+                if bseg.kind == "attention":
+                    q = np.stack(xs)
+                    kT = np.stack([np.asarray(envs[r][steps[0].weight_name],
+                                              np.float32) for r in range(n)])
+                    v = np.stack([np.asarray(envs[r][steps[1].weight_name],
+                                             np.float32) for r in range(n)])
+                else:
+                    # static: stack along M, zero-pad to the bucket
+                    X = np.concatenate(xs, axis=0)
+                    if n < plan.bucket:
+                        X = np.concatenate(
+                            [X, np.zeros(((plan.bucket - n) * bseg.m_rows,
+                                          X.shape[1]), np.float32)], axis=0)
             if bseg.kind == "attention":
-                kT = np.stack([np.asarray(envs[r][steps[0].weight_name],
-                                          np.float32) for r in range(n)])
-                v = np.stack([np.asarray(envs[r][steps[1].weight_name],
-                                         np.float32) for r in range(n)])
                 with trace.span("batch_segment", kind="attention",
                                 batch=n):
                     out = be.run_batched_attention(
-                        tuple(bseg.programs), np.stack(xs), kT, v, lengths)
+                        tuple(bseg.programs), q, kT, v, lengths)
                 outs = [np.asarray(out[r]) for r in range(n)]
                 if bseg.host_act is not None:
-                    outs = [np.asarray(bseg.host_act(o)) for o in outs]
+                    with trace.span("host.act"):
+                        outs = [np.asarray(bseg.host_act(o)) for o in outs]
                 prevs = outs
                 continue
-            # static: stack along M, zero-pad to the bucket, one launch
+            # static: one launch for the whole batch
             m_rows = bseg.m_rows
-            X = np.concatenate(xs, axis=0)
-            if n < plan.bucket:
-                X = np.concatenate(
-                    [X, np.zeros(((plan.bucket - n) * m_rows, X.shape[1]),
-                                 np.float32)], axis=0)
             if fused and bseg.fused is not None:
                 t = {"I": X}
                 for j, s in enumerate(steps):
@@ -765,7 +781,8 @@ class ModelExecutable:
                                          [prog.out_name])
             out = out[:n * m_rows]
             if bseg.host_act is not None:
-                out = np.asarray(bseg.host_act(out))
+                with trace.span("host.act"):
+                    out = np.asarray(bseg.host_act(out))
             prevs = [out[r * m_rows:(r + 1) * m_rows] for r in range(n)]
         return prevs
 
@@ -779,11 +796,14 @@ class ModelExecutable:
             if s.input_mode == "fresh":
                 t["I"] = env[s.input_name]
             elif s.input_mode == "adapt":
-                t["I"] = adapt(prev, g.m, g.k)
-            out = np.asarray(be.run_program(s.program, t)
-                             [s.program.out_name])
+                with trace.span("host.stage", kind="perreq"):
+                    t["I"] = adapt(prev, g.m, g.k)
+            with be.weight_kind(s.weight_kind):
+                out = np.asarray(be.run_program(s.program, t)
+                                 [s.program.out_name])
             if s.host_act is not None:
-                out = np.asarray(s.host_act(out))
+                with trace.span("host.act"):
+                    out = np.asarray(s.host_act(out))
             prev = out
         return prev
 

@@ -293,32 +293,33 @@ class SchedulerReport:
             })
         return out
 
-    def publish_metrics(self, registry=None) -> None:
+    def publish_metrics(self, registry=None, retired=None) -> None:
         """Push the serving totals into the metrics registry (default:
         the shared ``obs.metrics`` one): MINISA vs micro instruction
         bytes and token counters, the scalar summary as gauges, and the
         KVPool + cache stats -- one scrape surface over every ad-hoc
-        stats dict."""
+        stats dict.  The counters grow by ``retired`` (default: every
+        request of the report), the requests not yet counted."""
         reg = registry if registry is not None else obs_metrics.REGISTRY
+        new = self.requests if retired is None else retired
         reg.counter("minisa_bytes_total",
                     "MINISA instruction bytes served").inc(
-                        sum(r.minisa_bytes for r in self.requests),
+                        sum(r.minisa_bytes for r in new),
                         backend=self.backend)
         reg.counter("micro_bytes_total",
                     "micro-instruction control bytes (baseline)").inc(
-                        sum(r.micro_bytes for r in self.requests),
+                        sum(r.micro_bytes for r in new),
                         backend=self.backend)
         reg.counter("tokens_total", "tokens served").inc(
-            self.total_tokens, backend=self.backend)
+            sum(r.tokens for r in new), backend=self.backend)
         reg.counter("requests_total", "requests retired").inc(
-            len(self.requests), backend=self.backend)
-        timed_out = sum(1 for r in self.requests
-                        if r.status == "timed_out")
+            len(new), backend=self.backend)
+        timed_out = sum(1 for r in new if r.status == "timed_out")
         if timed_out:
             reg.counter("requests_timed_out_total",
                         "requests retired past their deadline").inc(
                             timed_out, backend=self.backend)
-        retries = sum(r.retries for r in self.requests)
+        retries = sum(r.retries for r in new)
         if retries:
             reg.counter("retries_total",
                         "fault-retried request steps").inc(
@@ -446,9 +447,11 @@ class PagedKV:
     tensors, so state checksums are independent of the paging layout.
     """
 
-    def __init__(self, pool: KVPool, pages: list[int]):
+    def __init__(self, pool: KVPool, pages: list[int],
+                 rid: int | None = None):
         self.pool = pool
         self.pages = pages
+        self.rid = rid                  # the owning request (span label)
 
     def _slot(self, j: int) -> int:
         ps = self.pool.page_size
@@ -466,20 +469,26 @@ class PagedKV:
         """Deterministic bounded KV append: fold the step output into
         one time slot of each dynamic operand (same fold as the
         pre-paging ``_commit_kv``, same quantisation)."""
-        vec = _stabilize(np.tanh(np.asarray(out, np.float32).ravel()))
-        if vec.size == 0:
-            return
-        for name, (_, _, t_ext, width) in self.pool.specs.items():
-            arena = self.pool.arenas[name]
-            arena[self._slot(pos % t_ext), :] = np.resize(vec, width)
+        with trace.span("kv.commit", rid=self.rid):
+            vec = _stabilize(np.tanh(np.asarray(out, np.float32).ravel()))
+            if vec.size == 0:
+                return
+            for name, (_, _, t_ext, width) in self.pool.specs.items():
+                arena = self.pool.arenas[name]
+                arena[self._slot(pos % t_ext), :] = np.resize(vec, width)
 
     def gather(self) -> dict[str, np.ndarray]:
         out = {}
-        for name, (shape, tax, t_ext, _) in self.pool.specs.items():
-            arena = self.pool.arenas[name]
-            rows = np.stack([arena[self._slot(j)] for j in range(t_ext)]) \
-                if t_ext else np.zeros(shape, np.float32)
-            out[name] = np.ascontiguousarray(rows if tax == 0 else rows.T)
+        with trace.span("kv.gather", rid=self.rid) as sp:
+            for name, (shape, tax, t_ext, _) in self.pool.specs.items():
+                arena = self.pool.arenas[name]
+                rows = np.stack([arena[self._slot(j)]
+                                 for j in range(t_ext)]) \
+                    if t_ext else np.zeros(shape, np.float32)
+                out[name] = np.ascontiguousarray(rows if tax == 0
+                                                 else rows.T)
+            if sp:
+                sp.set(bytes=sum(a.nbytes for a in out.values()))
         return out
 
     def release(self) -> None:
@@ -653,6 +662,7 @@ class Scheduler:
         self._active: list[_Active] = []
         self._done: list[RequestReport] = []
         self._restored: list[RequestReport] = []
+        self._n_published = 0           # _done entries in the registry
         self._ticks = 0
 
     def submit(self, decode_steps: int, seed: int | None = None,
@@ -693,7 +703,8 @@ class Scheduler:
             return None
         trace.instant("admit", ("request", req.rid), pages=len(pages))
         # request wall time runs from submission (queueing included)
-        a = _Active(req=req, kv=PagedKV(self.kv_pool, pages), carry=None,
+        a = _Active(req=req, kv=PagedKV(self.kv_pool, pages, req.rid),
+                    carry=None,
                     t_start=req.t_submit or time.perf_counter(),
                     prefill_chunks=self._chunks_for(req))
         try:
@@ -774,10 +785,10 @@ class Scheduler:
         guard on, each request's row is checked before its commit:
         poisoned rows fault (and retry), clean rows commit -- one bad
         launch cannot wedge the whole batch."""
+        envs = [self._decode_env(a) for a in batch]
         t0 = time.perf_counter() if trace.enabled else 0.0
-        finals = self.decode.run_batch(
-            self.backend, [self._decode_env(a) for a in batch],
-            fused=self.use_fused)
+        finals = self.decode.run_batch(self.backend, envs,
+                                       fused=self.use_fused)
         if trace.enabled:
             t1 = time.perf_counter()
             for a in batch:
@@ -959,9 +970,11 @@ class Scheduler:
     # -- the serving loop -----------------------------------------------------
     def run(self, max_ticks: int | None = None) -> SchedulerReport:
         """Serve every submitted request to completion.  The loop runs
-        under a ``scheduler.run`` span; on return the report's totals
-        (plus the cache's per-tier stats) are published into the shared
-        metrics registry.
+        under a ``scheduler.run`` span; its tail (``scheduler.report``)
+        builds the report and publishes it into the shared metrics
+        registry, the cache's per-tier stats too.  Counters grow by the
+        requests retired since the last publish, so each retired
+        request counts once however often ``run`` is called.
 
         ``max_ticks`` stops the loop early (chaos-kill simulation / an
         external drain signal): unfinished requests stay in the
@@ -970,10 +983,7 @@ class Scheduler:
         with trace.span("scheduler.run", backend=self.backend_name,
                         batch_decode=self.batch_decode,
                         max_concurrent=self.max_concurrent):
-            report = self._run_loop(max_ticks)
-        report.publish_metrics()
-        self.prefill.cache.publish_metrics()
-        return report
+            return self._run_loop(max_ticks)
 
     def _retire(self, a: _Active, per_bytes: list, per_cycles: list,
                 done: list) -> None:
@@ -1060,6 +1070,9 @@ class Scheduler:
                 with trace.span("decode_tick", tick=ticks,
                                 n_ready=len(ready),
                                 batched=self.batch_decode) as sp:
+                    if sp:
+                        h0 = self.backend.n_h2d_bytes
+                        d0 = self.backend.n_d2h_bytes
                     try:
                         if self.batch_decode:
                             self._decode_batch(ready)
@@ -1076,7 +1089,9 @@ class Scheduler:
                             self._on_fault(a, e)
                     if sp:
                         sp.set(launches=getattr(self.backend,
-                                                "n_launches", 0) - l0)
+                                                "n_launches", 0) - l0,
+                               h2d_bytes=self.backend.n_h2d_bytes - h0,
+                               d2h_bytes=self.backend.n_d2h_bytes - d0)
                 decode_wall += time.perf_counter() - td
                 decode_launches += (getattr(self.backend, "n_launches", 0)
                                     - l0)
@@ -1137,26 +1152,31 @@ class Scheduler:
             prefill_wall += time.perf_counter() - tp
         if self.injector is not None and not (self._pending or active):
             self._release_due_spikes(drain=True)
-        done = sorted(self._restored + done, key=lambda r: r.rid)
-        fusion = self.decode.fusion_stats()
-        return SchedulerReport(
-            backend=self.backend_name, requests=done,
-            wall_s=time.perf_counter() - t0, ticks=self._ticks,
-            max_concurrent=self.max_concurrent,
-            cache=self.prefill.cache.stats.summary(),
-            n_arrays=self.prefill.n_arrays,
-            per_array_minisa_bytes=per_bytes,
-            per_array_cycles=per_cycles,
-            decode_fused=self.use_fused,
-            decode_fused_segments=fusion["n_fused_segments"],
-            decode_segments=fusion["n_segments"],
-            decode_hbm_elided_bytes=(fusion["hbm_bytes_elided"]
-                                     if self.use_fused else 0.0),
-            batch_decode=self.batch_decode,
-            decode_wall_s=decode_wall,
-            prefill_wall_s=prefill_wall,
-            decode_steps_total=decode_steps_total,
-            decode_ticks=decode_ticks,
-            decode_launches=decode_launches,
-            kv=self.kv_pool.stats(),
-            resilience=self._resilience_summary(done))
+        with trace.span("scheduler.report"):
+            done = sorted(self._restored + self._done, key=lambda r: r.rid)
+            fusion = self.decode.fusion_stats()
+            report = SchedulerReport(
+                backend=self.backend_name, requests=done,
+                wall_s=time.perf_counter() - t0, ticks=self._ticks,
+                max_concurrent=self.max_concurrent,
+                cache=self.prefill.cache.stats.summary(),
+                n_arrays=self.prefill.n_arrays,
+                per_array_minisa_bytes=per_bytes,
+                per_array_cycles=per_cycles,
+                decode_fused=self.use_fused,
+                decode_fused_segments=fusion["n_fused_segments"],
+                decode_segments=fusion["n_segments"],
+                decode_hbm_elided_bytes=(fusion["hbm_bytes_elided"]
+                                         if self.use_fused else 0.0),
+                batch_decode=self.batch_decode,
+                decode_wall_s=decode_wall,
+                prefill_wall_s=prefill_wall,
+                decode_steps_total=decode_steps_total,
+                decode_ticks=decode_ticks,
+                decode_launches=decode_launches,
+                kv=self.kv_pool.stats(),
+                resilience=self._resilience_summary(done))
+            report.publish_metrics(retired=self._done[self._n_published:])
+            self._n_published = len(self._done)
+            self.prefill.cache.publish_metrics()
+        return report

@@ -32,7 +32,7 @@ def _clean_tracer():
     trace.clear()
 
 
-def _serve(backend, **kw):
+def _scheduler(backend, **kw):
     cache = ProgramCache()
     prefill = ModelExecutable.for_cell("gemma-7b", "prefill_tiny", CFG,
                                        cache=cache)
@@ -42,7 +42,11 @@ def _serve(backend, **kw):
                       max_concurrent=3, seed=0, **kw)
     for steps, prompt in SUBMISSIONS:
         sched.submit(decode_steps=steps, prompt_tokens=prompt)
-    return sched.run()
+    return sched
+
+
+def _serve(backend, **kw):
+    return _scheduler(backend, **kw).run()
 
 
 def _checksums(rep):
@@ -250,6 +254,113 @@ def test_span_breakdown_decode_tick(traced_serving):
     assert bd["n_children"] > 0
     assert 0.0 < bd["child_frac"] <= 1.0
     assert bd["child_frac"] + bd["host_frac"] == pytest.approx(1.0)
+
+
+# ---------------------------------------------------------------------------
+# Inside the decode tick: parent links, uploads, the KV gather
+# ---------------------------------------------------------------------------
+
+def _by_tick(events, name):
+    """decode_tick id -> the ``name`` spans below it (through parents)."""
+    by_id = {e.id: e for e in events}
+    out = {e.id: [] for e in events if e.name == "decode_tick"}
+    for e in events:
+        if e.name != name:
+            continue
+        p = e.parent
+        while p is not None and by_id[p].name != "decode_tick":
+            p = by_id[p].parent
+        if p is not None:
+            out[p].append(e)
+    return out
+
+
+def _plan_upload_bytes(decode, n):
+    """Bytes one batched tick of ``n`` requests uploads, from the batch
+    plan's shapes: each launch's fp32 input and weight (K^T and V for
+    attention); a chained layer's input is its producer's output."""
+    total = 0
+    for seg in decode.batch_plan(n).segments:
+        if seg.kind == "perreq":
+            gs = [decode.steps[i].program.gemm for i in seg.indices] * n
+            total += sum(g.m * g.k + g.k * g.n for g in gs)
+        elif seg.kind == "attention":
+            qk, pv = (p.gemm for p in seg.programs)
+            total += n * (qk.m * qk.k + qk.k * qk.n + pv.k * pv.n)
+        elif seg.fused is not None:
+            gs = [p.gemm for p in seg.fused.programs]
+            total += gs[0].m * gs[0].k + sum(g.k * g.n for g in gs)
+        else:
+            total += sum(p.gemm.m * p.gemm.k + p.gemm.k * p.gemm.n
+                         for p in seg.programs)
+    return 4 * total
+
+
+def test_span_parents_are_enclosing_spans(traced_serving):
+    _, events, _ = traced_serving
+    by_id = {e.id: e for e in events}
+    assert len(by_id) == len(events)
+    children = [e for e in events if e.parent is not None]
+    assert children
+    for e in children:
+        p = by_id[e.parent]
+        assert not p.instant
+        assert p.t0_s <= e.t0_s + 1e-9 and e.t1_s <= p.t1_s + 1e-9, \
+            (e.name, p.name)
+    names = {(by_id[e.parent].name, e.name) for e in children}
+    assert {("decode_tick", "decode_step"), ("launch", "backend.put"),
+            ("launch", "backend.wait"), ("launch", "backend.fetch"),
+            ("scheduler.run", "scheduler.report")} <= names
+
+
+def test_decode_tick_bytes_are_its_puts_and_the_plans(traced_serving):
+    _, events, _ = traced_serving
+    decode = ModelExecutable.for_cell("gemma-7b", "decode_tiny", CFG,
+                                      cache=ProgramCache())
+    puts = _by_tick(events, "backend.put")
+    fetches = _by_tick(events, "backend.fetch")
+    ticks = [e for e in events if e.name == "decode_tick"]
+    assert ticks
+    for t in ticks:
+        assert t.attrs["h2d_bytes"] == sum(e.attrs["bytes"]
+                                           for e in puts[t.id])
+        assert t.attrs["h2d_bytes"] == \
+            _plan_upload_bytes(decode, t.attrs["n_ready"])
+        assert t.attrs["d2h_bytes"] == sum(e.attrs["bytes"]
+                                           for e in fetches[t.id]) > 0
+        assert {e.attrs["operand"] for e in puts[t.id]} == \
+            {"input", "weight", "kv"}
+
+
+def test_each_tick_gathers_kv_once_per_ready_request(traced_serving):
+    _, events, _ = traced_serving
+    gathers = _by_tick(events, "kv.gather")
+    for t in (e for e in events if e.name == "decode_tick"):
+        rids = [e.attrs["rid"] for e in gathers[t.id]]
+        assert len(rids) == len(set(rids)) == t.attrs["n_ready"]
+        assert all(e.attrs["bytes"] > 0 for e in gathers[t.id])
+
+
+def test_repeated_short_runs_count_each_request_once():
+    """``run(max_ticks=1)`` until every request retires: the counters
+    count each retired request once, as one ``run()`` does."""
+    metrics.reset()
+    sched = _scheduler("interpreter")
+    calls = 0
+    rep = None
+    while rep is None or len(rep.requests) < len(SUBMISSIONS):
+        rep = sched.run(max_ticks=1)
+        calls += 1
+    assert calls > 1
+    snap = metrics.snapshot()
+    label = '{backend="interpreter"}'
+    assert snap["tokens_total"][label] == rep.total_tokens
+    assert snap["requests_total"][label] == len(SUBMISSIONS)
+    assert snap["minisa_bytes_total"][label] == \
+        pytest.approx(sum(r.minisa_bytes for r in rep.requests))
+    assert snap["micro_bytes_total"][label] == \
+        pytest.approx(sum(r.micro_bytes for r in rep.requests))
+    assert rep.total_tokens == _serve("interpreter").total_tokens
 
 
 # ---------------------------------------------------------------------------
