@@ -59,6 +59,9 @@ class Backend(abc.ABC):
         #: only; the interpreter never leaves the host)
         self.n_h2d_bytes = 0
         self.n_d2h_bytes = 0
+        #: weight operands found already resident on the device, so not
+        #: copied (compiled backends only)
+        self.n_weight_hits = 0
         #: what ``run_program``'s 'W' operand holds (see weight_kind)
         self._weight_kind = "weight"
         # one executor per logical array, created on first sharded run
@@ -217,13 +220,15 @@ class Backend(abc.ABC):
 
     @contextlib.contextmanager
     def _fold_bytes(self, sub: "Backend"):
-        """Count the bytes a shard executor copies as this backend's."""
-        h2d, d2h = sub.n_h2d_bytes, sub.n_d2h_bytes
+        """Count the bytes a shard executor copies, and the weight copies
+        it skips, as this backend's."""
+        h2d, d2h, hits = sub.n_h2d_bytes, sub.n_d2h_bytes, sub.n_weight_hits
         try:
             yield
         finally:
             self.n_h2d_bytes += sub.n_h2d_bytes - h2d
             self.n_d2h_bytes += sub.n_d2h_bytes - d2h
+            self.n_weight_hits += sub.n_weight_hits - hits
 
     def _shard_backend(self, array: int) -> "Backend":
         be = self._shard_subs.get(array)

@@ -50,6 +50,7 @@ vanish structurally.
 
 from __future__ import annotations
 
+import collections
 import dataclasses
 import math
 from typing import TYPE_CHECKING, Any
@@ -70,6 +71,16 @@ from repro.obs.trace import trace
 #: diffs and the process-wide scrape agree on what actually launched
 _LAUNCHES = obs_metrics.counter(
     "backend_launches_total", "pallas_call launches by kernel and device")
+_WEIGHT_HITS = obs_metrics.counter(
+    "backend_resident_weight_hits_total",
+    "weight operands found resident on the device, so not uploaded")
+_RESIDENT_BYTES = obs_metrics.gauge(
+    "backend_resident_weight_bytes",
+    "bytes of static weights a backend keeps on its device")
+
+#: bound on the resident weights where the device reports no memory
+#: limit (the CPU backend)
+_RESIDENT_FALLBACK_BYTES = 1 << 30
 
 if TYPE_CHECKING:  # pragma: no cover
     from repro.configs.feather import FeatherConfig
@@ -231,6 +242,22 @@ def compile_program(program: "Program", *,
         residency=dict(program.residency))
 
 
+def _static(x) -> bool:
+    """A host array whose values cannot change under the backend: fp32
+    (uploaded as it is, not as a fresh copy), read-only, and owning its
+    data, so no writeable base aliases it."""
+    return (isinstance(x, np.ndarray) and x.dtype == np.float32
+            and not x.flags.writeable and x.flags.owndata)
+
+
+def _resident_bound(device) -> int:
+    """Bytes of weights a backend keeps resident: half the device's
+    memory limit, or a fixed bound where the device reports none."""
+    d = device if device is not None else jax.devices()[0]
+    limit = (d.memory_stats() or {}).get("bytes_limit")
+    return limit // 2 if limit else _RESIDENT_FALLBACK_BYTES
+
+
 class PallasBackend(Backend):
     """Compiled execution: one Pallas kernel launch per Program."""
 
@@ -262,6 +289,15 @@ class PallasBackend(Backend):
         # kernel launches this instance performed (one pallas_call each);
         # the Scheduler diffs this to prove one-launch-per-segment ticks
         self.n_launches = 0
+        # static weights kept on the device across launches (see _put):
+        # id(host) -> (host, device array), least recently used first.
+        # The entry pins the host array, so its id cannot be reused while
+        # the entry lives.  The byte bound is read from the device on the
+        # first upload.
+        self._resident: collections.OrderedDict[
+            int, tuple[np.ndarray, jax.Array]] = collections.OrderedDict()
+        self._resident_bytes = 0
+        self._resident_limit: int | None = None
 
     def compile(self, program: "Program") -> CompiledProgram:
         key = id(program)
@@ -388,16 +424,30 @@ class PallasBackend(Backend):
 
     def _count_launch(self, kernel: str, device: str | None = None) -> None:
         self.n_launches += 1
-        _LAUNCHES.inc(1, kernel=kernel,
-                      device=device or str(self.device or "default"))
+        _LAUNCHES.inc(1, kernel=kernel, device=device or self._device_label)
 
     def _put(self, x, operand: str) -> jax.Array:
         """Host operand -> fp32 device array on this backend's device.
 
-        Under tracing the ``backend.put`` span waits for the transfer,
-        so it times the upload; ``operand`` is ``input``, ``weight`` or
-        ``kv``."""
-        host = np.asarray(x, np.float32)
+        A ``weight`` that cannot change (:func:`_static`) is uploaded
+        once and kept resident: later calls with the same array return
+        the device copy, count a hit and open no span.  Everything else
+        is uploaded on every call.  Under tracing the ``backend.put``
+        span waits for the transfer, so it times the upload;
+        ``operand`` is ``input``, ``weight`` or ``kv``."""
+        if operand == "weight" and _static(x):
+            hit = self._resident.get(id(x))
+            if hit is not None and hit[0] is x:
+                self._resident.move_to_end(id(x))
+                self.n_weight_hits += 1
+                _WEIGHT_HITS.inc(1, device=self._device_label)
+                return hit[1]
+            arr = self._upload(x, operand)
+            self._keep(x, arr)
+            return arr
+        return self._upload(np.asarray(x, np.float32), operand)
+
+    def _upload(self, host: np.ndarray, operand: str) -> jax.Array:
         self.n_h2d_bytes += host.nbytes
         with trace.span("backend.put") as sp:
             arr = jax.device_put(host, self.device)
@@ -405,6 +455,25 @@ class PallasBackend(Backend):
                 sp.set(bytes=host.nbytes, operand=operand)
                 arr.block_until_ready()
         return arr
+
+    def _keep(self, host: np.ndarray, arr: jax.Array) -> None:
+        """Make ``arr`` resident for ``host``, evicting the least
+        recently used weights past the byte bound (an evicted weight is
+        uploaded again on its next use)."""
+        if self._resident_limit is None:
+            self._resident_limit = _resident_bound(self.device)
+        if host.nbytes > self._resident_limit:
+            return
+        self._resident[id(host)] = (host, arr)
+        self._resident_bytes += host.nbytes
+        while self._resident_bytes > self._resident_limit:
+            _, (old, _) = self._resident.popitem(last=False)
+            self._resident_bytes -= old.nbytes
+        _RESIDENT_BYTES.set(self._resident_bytes, device=self._device_label)
+
+    @property
+    def _device_label(self) -> str:
+        return str(self.device or "default")
 
     def _finish(self, kernel: str, dispatch) -> np.ndarray:
         """Run ``dispatch`` (one kernel launch) and copy its output to
@@ -538,3 +607,6 @@ class PallasBackend(Backend):
         self._committed = None
         self._cache = {}
         self._fused_cache = {}
+        self._resident.clear()
+        self._resident_bytes = 0
+        _RESIDENT_BYTES.set(0, device=self._device_label)

@@ -8,7 +8,8 @@ One prefill and one decode :class:`~repro.runtime.executable.ModelExecutable`
   * **weight residency**: the static weight tensors are generated once
     per scheduler and shared by all requests (only *dynamic* operands --
     the attention K^T/V, FEATHER+'s runtime-layout case -- are
-    per-request state);
+    per-request state); they are read-only, so the Pallas backend
+    uploads each once and keeps it on the device;
   * **KV residency**: each request's dynamic tensors live in a paged
     :class:`KVPool` arena for the request's lifetime; every step's
     output is committed back into them (a deterministic bounded update
@@ -649,11 +650,16 @@ class Scheduler:
                 f"kv_pages={kv_pages} cannot hold even one request "
                 f"({per_req} pages of {kv_page_size} slots needed)")
         self.kv_pool = KVPool(specs, kv_page_size, kv_pages)
-        # weight residency: one static weight set serves every request
+        # weight residency: one static weight set serves every request.
+        # Read-only, so a compiled backend keeps each on its device and
+        # uploads it once.
         self.prefill_weights = prefill.make_tensors(weight_seed,
                                                     kinds=("weight",))
         self.decode_weights = decode.make_tensors(weight_seed,
                                                   kinds=("weight",))
+        for arr in (*self.prefill_weights.values(),
+                    *self.decode_weights.values()):
+            arr.setflags(write=False)
         self._pending: collections.deque[Request] = collections.deque()
         self._next_rid = 0
         # serving state shared between the loop, snapshot() and resumed
@@ -1073,6 +1079,7 @@ class Scheduler:
                     if sp:
                         h0 = self.backend.n_h2d_bytes
                         d0 = self.backend.n_d2h_bytes
+                        w0 = self.backend.n_weight_hits
                     try:
                         if self.batch_decode:
                             self._decode_batch(ready)
@@ -1091,7 +1098,9 @@ class Scheduler:
                         sp.set(launches=getattr(self.backend,
                                                 "n_launches", 0) - l0,
                                h2d_bytes=self.backend.n_h2d_bytes - h0,
-                               d2h_bytes=self.backend.n_d2h_bytes - d0)
+                               d2h_bytes=self.backend.n_d2h_bytes - d0,
+                               weight_hits=(self.backend.n_weight_hits
+                                            - w0))
                 decode_wall += time.perf_counter() - td
                 decode_launches += (getattr(self.backend, "n_launches", 0)
                                     - l0)

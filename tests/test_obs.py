@@ -275,25 +275,30 @@ def _by_tick(events, name):
     return out
 
 
-def _plan_upload_bytes(decode, n):
-    """Bytes one batched tick of ``n`` requests uploads, from the batch
-    plan's shapes: each launch's fp32 input and weight (K^T and V for
-    attention); a chained layer's input is its producer's output."""
-    total = 0
+def _plan_uploads(decode, n):
+    """(operand, bytes) of each upload one batched tick of ``n`` requests
+    makes before any weight is resident, from the batch plan's shapes:
+    each launch's fp32 input and weight (K^T and V for attention); a
+    chained layer's input is its producer's output."""
+    ups = []
     for seg in decode.batch_plan(n).segments:
         if seg.kind == "perreq":
-            gs = [decode.steps[i].program.gemm for i in seg.indices] * n
-            total += sum(g.m * g.k + g.k * g.n for g in gs)
+            for s in [decode.steps[i] for i in seg.indices] * n:
+                g = s.program.gemm
+                ups += [("input", g.m * g.k), (s.weight_kind, g.k * g.n)]
         elif seg.kind == "attention":
             qk, pv = (p.gemm for p in seg.programs)
-            total += n * (qk.m * qk.k + qk.k * qk.n + pv.k * pv.n)
+            ups += [("input", n * qk.m * qk.k), ("kv", n * qk.k * qk.n),
+                    ("kv", n * pv.k * pv.n)]
         elif seg.fused is not None:
             gs = [p.gemm for p in seg.fused.programs]
-            total += gs[0].m * gs[0].k + sum(g.k * g.n for g in gs)
+            ups += [("input", gs[0].m * gs[0].k)]
+            ups += [("weight", g.k * g.n) for g in gs]
         else:
-            total += sum(p.gemm.m * p.gemm.k + p.gemm.k * p.gemm.n
-                         for p in seg.programs)
-    return 4 * total
+            for p in seg.programs:
+                ups += [("input", p.gemm.m * p.gemm.k),
+                        ("weight", p.gemm.k * p.gemm.n)]
+    return [(op, 4 * n_values) for op, n_values in ups]
 
 
 def test_span_parents_are_enclosing_spans(traced_serving):
@@ -314,22 +319,33 @@ def test_span_parents_are_enclosing_spans(traced_serving):
 
 
 def test_decode_tick_bytes_are_its_puts_and_the_plans(traced_serving):
+    """Every tick uploads what its ``backend.put``s say; the first tick
+    uploads the plan's every operand, and each later one only its inputs
+    and K/V, its weights already resident (one hit each)."""
     _, events, _ = traced_serving
     decode = ModelExecutable.for_cell("gemma-7b", "decode_tiny", CFG,
                                       cache=ProgramCache())
     puts = _by_tick(events, "backend.put")
     fetches = _by_tick(events, "backend.fetch")
     ticks = [e for e in events if e.name == "decode_tick"]
-    assert ticks
-    for t in ticks:
+    assert len(ticks) > 1
+    for i, t in enumerate(ticks):
+        plan = _plan_uploads(decode, t.attrs["n_ready"])
+        n_weights = sum(op == "weight" for op, _ in plan)
         assert t.attrs["h2d_bytes"] == sum(e.attrs["bytes"]
                                            for e in puts[t.id])
-        assert t.attrs["h2d_bytes"] == \
-            _plan_upload_bytes(decode, t.attrs["n_ready"])
         assert t.attrs["d2h_bytes"] == sum(e.attrs["bytes"]
                                            for e in fetches[t.id]) > 0
-        assert {e.attrs["operand"] for e in puts[t.id]} == \
-            {"input", "weight", "kv"}
+        operands = {e.attrs["operand"] for e in puts[t.id]}
+        if i == 0:
+            assert t.attrs["h2d_bytes"] == sum(b for _, b in plan)
+            assert t.attrs["weight_hits"] == 0
+            assert operands == {"input", "weight", "kv"}
+        else:
+            assert t.attrs["h2d_bytes"] == \
+                sum(b for op, b in plan if op != "weight")
+            assert t.attrs["weight_hits"] == n_weights > 0
+            assert operands == {"input", "kv"}
 
 
 def test_each_tick_gathers_kv_once_per_ready_request(traced_serving):
