@@ -4,12 +4,12 @@
 
 In one process (set-up is long, and one process holds the chip), for
 each seed: the cell is set up as a run sets it up, served for
-``--seconds`` at its own load, and the window's sampled answers are read
-twice: the program against the reference, and the control (the
-reference computed in three bf16 passes, ``Precision.HIGH``) in the
-program's place against the same reference.  One JSON line per seed,
-then the largest program reading and the smallest control reading of
-each number.  The benchmark's own runs never run this.
+``--seconds`` at its own load, and the window's answers are read twice
+by the configuration's module: the program against the reference, and
+the control (the reference in the precision below the configuration's)
+in the program's place against the same reference.  One JSON line per
+seed, then the largest program reading and the smallest control reading
+of each number.  The benchmark's own runs never run this.
 """
 
 from __future__ import annotations
@@ -34,11 +34,12 @@ def main(argv=None) -> int:
     ap.add_argument("--seconds", type=float, default=10.0)
     args = ap.parse_args(argv)
     sys.path[:0] = [str(ROOT), str(ROOT / "src")]
-    from bench import correctness, harness
+    from bench import harness, models
     from bench.run import require_devices
 
     bench = json.loads((ROOT / "BENCHMARK.json").read_text())
     spec = harness.load_cell(bench, args.workload)
+    model = models.load(spec["config"])
     require_devices(spec["chips"])
     from repro import jax_cache
     jax_cache.enable()
@@ -49,14 +50,12 @@ def main(argv=None) -> int:
         rec.armed = True
         window.run(args.seconds)
         rec.armed = False
-        calls, served = harness.sampled_calls(rec)
+        collected = rec.collect()
         del sched, window, rec
         gc.collect()
-        prog, ctrl = correctness.readings(
-            spec["config"], calls, served, weight_seed=seed,
-            kv_extent=spec["mix"]["kv_extent"], control=True)
+        prog, ctrl = model.readings(spec["config"], spec["mix"], collected,
+                                    weight_seed=seed, control=True)
         line = {"seed": seed, "program": prog, "control": ctrl,
-                "sampled": {k: len(v) for k, v in calls.items()},
                 "seconds": time.perf_counter() - t0}
         lines.append(line)
         print(json.dumps(line), flush=True)

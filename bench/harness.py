@@ -18,13 +18,11 @@ import shutil
 import sys
 import time
 
-from bench import adapter, cell, correctness, stats
+from bench import adapter, models, stats
 from bench.traffic.generate import generate
 
 ROOT = pathlib.Path(__file__).resolve().parents[1]
 TRACE_DIR = ROOT / "bench_out" / "trace"
-#: sampled calls of each kind whose answers are checked
-SAMPLED_CALLS = {"prefill": 2, "decode": 8}
 
 
 def log(msg: str) -> None:
@@ -118,36 +116,26 @@ class Window:
 
 
 def prepare(spec: dict, seed: int, trace: bool, t_start: float):
-    """Set-up: the cell built from the seed, every shape it reaches
-    warmed, the sessions admitted.  Returns the scheduler, the traffic
-    plan, the window and the (unarmed) recorder, which has seen every
-    request from its admission on."""
+    """Set-up: the cell built from the seed by its configuration's
+    module, every shape it reaches warmed, the sessions admitted.
+    Returns the scheduler, the traffic plan, the window and the
+    (unarmed) recorder, which has seen every request from its admission
+    on."""
     config, mix = spec["config"], spec["mix"]
-    sched = cell.build(config, mix, seed)
+    model = models.load(config)
+    sched = model.build(config, mix, seed)
     log(f"set-up: built, {time.perf_counter() - t_start:.3f} s")
-    warmed = cell.warm_up(sched, mix)
-    log(f"set-up: warmed decode batches {warmed}, "
+    warmed = model.warm_up(sched, mix)
+    log(f"set-up: warmed {warmed}, "
         f"{time.perf_counter() - t_start:.3f} s")
     plan = generate(mix, seed)
-    recorder = adapter.Recorder(sched, seed, SAMPLED_CALLS,
-                                correctness.served_lead(config, mix))
+    recorder = model.recorder(sched, config, mix, seed)
     window = Window(sched, plan, trace)
     for s in plan.sessions:
         window.submit(s)
     while adapter.backlog(sched):
         sched.run(max_ticks=1)
     return sched, plan, window, recorder
-
-
-def sampled_calls(recorder) -> tuple[dict, dict]:
-    """The recorded calls, decode ticks mapped to their steps, and every
-    request's served outputs; drops the recorder's hold on the
-    scheduler."""
-    calls = recorder.kept
-    for c in calls["decode"]:
-        c.step_rows = adapter.decode_step_rows(recorder.sched.decode, c)
-    recorder.sched = None
-    return calls, recorder.served
 
 
 def run(spec: dict, bench: dict, *, seed: int, seconds: float,
@@ -157,6 +145,7 @@ def run(spec: dict, bench: dict, *, seed: int, seconds: float,
     import jax
 
     config, mix = spec["config"], spec["mix"]
+    model = models.load(config)
     compiles = CompileCounter()
     sched, plan, window, recorder = prepare(spec, seed, trace, t_start)
     setup_s = time.perf_counter() - t_start
@@ -204,7 +193,8 @@ def run(spec: dict, bench: dict, *, seed: int, seconds: float,
     if trace:
         from bench import layers
         values, breakdown, extra = layers.measure(
-            bench, sched, window, tracer.events(), devices, TRACE_DIR)
+            bench, model, sched, window, tracer.events(), devices,
+            TRACE_DIR)
         result["metrics"] = _metrics(bench["per_layer"], spec["name"],
                                      values)
         device.update(extra)
@@ -218,17 +208,22 @@ def run(spec: dict, bench: dict, *, seed: int, seconds: float,
         result["device"] = device
 
     # the check: program state freed first, then the reference
-    calls, served = sampled_calls(recorder)
+    collected = recorder.collect()
     del recorder, sched, window
     gc.collect()
-    numbers = correctness.readings(config, calls, served, weight_seed=seed,
-                                   kv_extent=mix["kv_extent"])
-    limits = correctness.required(config["correct_limits"], calls)
-    result["correct"] = correctness.verdict(numbers, limits)
+    numbers = model.readings(config, mix, collected, weight_seed=seed)
+    limits = model.required(config["correct_limits"], collected)
+    result["correct"] = verdict(numbers, limits)
     compared = {k: {"value": numbers.get(k), "limit": v}
                 for k, v in limits.items()}
     result["compared"] = compared
     return result, compared
+
+
+def verdict(numbers: dict, limits: dict) -> bool:
+    """Correct when every number the run is held to was read and is
+    within its limit (a NaN never is)."""
+    return all(k in numbers and numbers[k] <= v for k, v in limits.items())
 
 
 def _metrics(entries, cell_name, values) -> dict:

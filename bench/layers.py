@@ -4,43 +4,15 @@ per-layer metric's reader run over them."""
 
 from __future__ import annotations
 
-import importlib
-
-from bench import adapter, peaks, trace_reduce
+from bench import peaks, trace_reduce
 from bench.metrics import Context, read
 
 
-def _kernel_cost(kernel, dims):
-    mod = importlib.import_module(f"bench.kernels.{kernel}")
-    return mod.cost(dims)
-
-
-def launches(sched, spans) -> dict:
-    """kernel -> [(flops, bytes)] of every launch the window's prefill
-    passes and decode ticks made."""
-    out: dict[str, list] = {}
-    one_pass = adapter.pass_launches(sched.prefill)
-    per_tick: dict[int, list] = {}
-    for e in spans:
-        if e.instant:
-            continue
-        if e.name == "prefill_chunk":
-            made = one_pass
-        elif e.name == "decode_tick":
-            n = e.attrs["n_ready"]
-            if n not in per_tick:
-                per_tick[n] = adapter.tick_launches(sched.decode, n)
-            made = per_tick[n]
-        else:
-            continue
-        for kernel, dims in made:
-            out.setdefault(kernel, []).append(_kernel_cost(kernel, dims))
-    return out
-
-
-def measure(bench, sched, window, spans, devices, trace_dir):
+def measure(bench, model, sched, window, spans, devices, trace_dir):
     """(per-layer values by name, breakdown, device extras) of the
-    traced window; a reader that finds nothing gives no value."""
+    traced window; a reader that finds nothing gives no value.  The
+    launches and their work come from the configuration's ``model``
+    module."""
     chip = peaks.for_device(devices[0].device_kind)
     tr = trace_reduce.load(trace_reduce.find_xplane(str(trace_dir)))
     win = next(e for e in tr.host if e.name == "bench.window")
@@ -50,11 +22,12 @@ def measure(bench, sched, window, spans, devices, trace_dir):
     offset = anchor.start_ns - obs_anchor.t0_s * 1e9
 
     busy = trace_reduce.busy_ns(tr, t0, t1) * 1e-9
+    launches = model.launches(sched, spans)
     kernels = {k: trace_reduce.kernel_ns(tr, k, t0, t1) * 1e-9
-               for k in ("nest_gemm", "fused_chain", "flash_decode")}
+               for k in launches}
     ctx = Context(spans=spans, window_s=window.t_end - window.t0,
                   trace_window_s=(t1 - t0) * 1e-9, busy_s=busy,
-                  kernel_s=kernels, launches=launches(sched, spans),
+                  kernel_s=kernels, launches=launches,
                   peaks=chip, chips=len(devices))
     values = {m["name"]: read(m["name"], ctx) for m in bench["per_layer"]}
 

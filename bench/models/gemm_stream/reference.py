@@ -23,6 +23,10 @@ stream step by step:
   slot's width (:func:`commit`); a pass's fresh inputs are the previous
   output as ``round(out, 3)``, refolded (:func:`fresh_inputs`).
 
+``run(..., bound=True)`` also carries each step's first-order
+rounding-error bound, which scales the error of a decode state that has
+grown until the softmax is sharp.
+
 ``precision="highest"`` is the reference.  ``precision="high"`` is its
 control: the same stream with every product in three bf16 passes
 (hi*hi + hi*lo + lo*hi, the ``Precision.HIGH`` algorithm), spelled out so
@@ -204,25 +208,54 @@ def _adapt(x, m, k):
     return flat[:, :need].reshape(b, m, k)
 
 
+def _act_bound(name, pre, post, s_pre):
+    """First-order bound on an activation's output error, in units of the
+    unit roundoff, from the bound ``s_pre`` on its input's: the
+    derivative carries the input's error, ``|post|`` adds the
+    activation's own rounding."""
+    import jax
+    import jax.numpy as jnp
+    if name == "softmax":
+        ps = post * s_pre
+        return ps + post * jnp.sum(ps, axis=-1, keepdims=True) + post
+    if name == "silu":
+        sig = jax.nn.sigmoid(pre)
+        return jnp.abs(sig * (1 + pre * (1 - sig))) * s_pre + jnp.abs(post)
+    return s_pre
+
+
 def run(steps: list[StepSpec], weights: dict, rows: dict,
-        precision: str = "highest"):
+        precision: str = "highest", bound: bool = False):
     """Evaluate the stream for a batch of B requests.
 
     ``weights``: name -> [k, n] shared operand (host or device array).
     ``rows``: name -> [B, ...] per-request operands (fresh inputs and
     dynamic K/V).  Yields ``(index, pre_act, post_act)`` per step, each
-    ``[B, m, n]`` float32 on the device."""
+    ``[B, m, n]`` float32 on the device.  With ``bound``, also each
+    step's first-order rounding-error bound in units of the unit
+    roundoff, ``(index, pre, post, bound_pre, bound_post)``: a product's
+    bound is ``(|x| + bound_x) @ |w|``, its own rounding and what its
+    input carries in; a fresh input and a K/V operand are exact."""
     import jax.numpy as jnp
-    prev = None
+    prev = s_prev = None
     for i, s in enumerate(steps):
         if prev is None or s.fresh:
             x = jnp.asarray(rows[f"I{i}"], jnp.float32)
+            s_in = None
         else:
             x = _adapt(prev, s.m, s.k)
+            s_in = _adapt(s_prev, s.m, s.k) if bound else None
         name = f"W{i}"
         w = jnp.asarray(rows[name] if s.dynamic else weights[name],
                         jnp.float32)
         pre = matmul(x, w, precision)
         post = _act(s.act, pre)
-        yield i, pre, post
-        prev = post
+        if not bound:
+            yield i, pre, post
+            prev = post
+            continue
+        a = jnp.abs(x) if s_in is None else jnp.abs(x) + s_in
+        s_pre = matmul(a, jnp.abs(w), "highest")
+        s_post = _act_bound(s.act, pre, post, s_pre)
+        yield i, pre, post, s_pre, s_post
+        prev, s_prev = post, s_post

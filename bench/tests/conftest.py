@@ -28,22 +28,39 @@ def load_json(rel: str) -> dict:
     return json.loads((ROOT / rel).read_text())
 
 
-@pytest.fixture
-def tiny_spec(monkeypatch):
+#: minitron4b at the widths the same reduction runs
+TINY_DENSE_WIDTHS = {"hidden_size": 64, "num_attention_heads": 4,
+                     "num_key_value_heads": 4, "head_dim": 16,
+                     "intermediate_size": 256, "vocab_size": 512,
+                     "prefill_chunk": 16}
+
+
+def _tiny(monkeypatch, name, config, mix, widths):
     from repro.configs import registry
     from repro.configs.base import reduced
 
     real = registry.get_config
     monkeypatch.setattr(registry, "get_config", lambda arch: reduced(
         real(arch), layers=1, d_model=64, vocab=512))
-    config = dict(load_json("bench/configs/granite3moe.json"),
-                  **TINY_WIDTHS)
+    config = dict(load_json(f"bench/configs/{config}.json"), **widths)
     # short outputs, so sessions finish and are replaced inside the window
-    mix = dict(load_json("bench/traffic/long_decode4.json"), kv_extent=32,
+    mix = dict(load_json(f"bench/traffic/{mix}.json"), kv_extent=32,
                prompt_tokens={"dist": "fixed", "value": 16},
                output_tokens={"dist": "uniform", "min": 3, "max": 9})
-    return {"name": "granite3moe.decode", "chips": 1, "config": config,
-            "mix": mix}
+    return {"name": name, "chips": 1, "config": config, "mix": mix}
+
+
+@pytest.fixture
+def tiny_spec(monkeypatch):
+    return _tiny(monkeypatch, "granite3moe.decode", "granite3moe",
+                 "long_decode4", TINY_WIDTHS)
+
+
+@pytest.fixture
+def tiny_dense_spec(monkeypatch):
+    """minitron4b.decode's cell, held to its own number."""
+    return _tiny(monkeypatch, "minitron4b.decode", "minitron4b",
+                 "long_decode", TINY_DENSE_WIDTHS)
 
 
 @pytest.fixture

@@ -3,14 +3,17 @@ a small size: a sound run passes, the control (the reference in three
 bf16 passes in the program's place) fails the same limits, and a run
 whose timed path is broken underneath reads ``correct`` false for each
 fault a serving cell can have, state left un-updated among them.  (A
-one-chip cell has no exchange between chips to leave out.)"""
+one-chip cell has no exchange between chips to leave out.)  Each cell's
+configuration is held to its own numbers: granite3moe's relative
+errors, and minitron4b's decode error over its rounding-error bound."""
 
 import time
 
 import numpy as np
 import pytest
 
-from bench import correctness, harness
+from bench import harness
+from bench.models import gemm_stream
 
 SECONDS = 2.0
 
@@ -34,22 +37,36 @@ def test_sound_run_is_correct(tiny_spec, bench_spec):
     assert result["attempted"] > 4 and result["failed"] == 0
 
 
-@pytest.mark.parametrize("seed", [5, 2**31 + 9])
-def test_control_fails_the_limits(tiny_spec, seed):
-    sched, _, window, rec = harness.prepare(tiny_spec, seed, False,
+def test_sound_dense_run_is_correct(tiny_dense_spec, bench_spec):
+    result, compared = _run(tiny_dense_spec, bench_spec)
+    assert result["correct"], compared
+    assert set(compared) == {"decode_err_over_bound"}
+
+
+def _control(spec, seed):
+    sched, _, window, rec = harness.prepare(spec, seed, False,
                                             time.perf_counter())
     rec.armed = True
     window.run(SECONDS)
     rec.armed = False
-    calls, served = harness.sampled_calls(rec)
-    prog, ctrl = correctness.readings(tiny_spec["config"], calls, served,
-                                      weight_seed=seed, kv_extent=32,
+    prog, ctrl = gemm_stream.readings(spec["config"], spec["mix"],
+                                      rec.collect(), weight_seed=seed,
                                       control=True)
-    limits = tiny_spec["config"]["correct_limits"]
+    limits = spec["config"]["correct_limits"]
     assert set(prog) == set(ctrl) == set(limits)
     assert all(prog[k] <= limits[k] for k in limits), prog
     assert any(ctrl[k] > limits[k] for k in limits), ctrl
     assert any(ctrl[k] > 3 * prog[k] for k in limits), (prog, ctrl)
+
+
+@pytest.mark.parametrize("seed", [5, 2**31 + 9])
+def test_control_fails_the_limits(tiny_spec, seed):
+    _control(tiny_spec, seed)
+
+
+@pytest.mark.parametrize("seed", [5, 751078467])
+def test_control_fails_the_bound_limit(tiny_dense_spec, seed):
+    _control(tiny_dense_spec, seed)
 
 
 def _stale_state(orig):
@@ -103,11 +120,23 @@ def _skipped_commit(orig):
     return commit
 
 
-@pytest.mark.parametrize("fault", ["stale_state", "half_batch",
-                                   "altered_answer", "kept_carry",
-                                   "skipped_commit"])
+FAULTS = ["stale_state", "half_batch", "altered_answer", "kept_carry",
+          "skipped_commit"]
+
+
+@pytest.mark.parametrize("fault", FAULTS)
 def test_broken_timed_path_is_not_correct(tiny_spec, bench_spec,
                                           monkeypatch, fault):
+    _broken(tiny_spec, bench_spec, monkeypatch, fault)
+
+
+@pytest.mark.parametrize("fault", FAULTS)
+def test_broken_dense_path_is_not_correct(tiny_dense_spec, bench_spec,
+                                          monkeypatch, fault):
+    _broken(tiny_dense_spec, bench_spec, monkeypatch, fault)
+
+
+def _broken(spec, bench_spec, monkeypatch, fault):
     from repro.backends.pallas_backend import PallasBackend
     from repro.runtime.executable import ModelExecutable
     from repro.runtime.scheduler import PagedKV, Scheduler
@@ -124,5 +153,5 @@ def test_broken_timed_path_is_not_correct(tiny_spec, bench_spec,
         make = _stale_state if fault == "stale_state" else _half_batch
         monkeypatch.setattr(ModelExecutable, "run_batch",
                             make(ModelExecutable.run_batch))
-    result, compared = _run(tiny_spec, bench_spec)
+    result, compared = _run(spec, bench_spec)
     assert result["correct"] is False, compared

@@ -88,52 +88,61 @@ def test_metrics_are_reported_where_they_move():
 
 
 @pytest.mark.parametrize("entry", BENCH["configs"], ids=lambda c: c["name"])
-def test_config_widths_match_the_program(entry):
-    from repro.configs.registry import get_config
+def test_config_file_is_well_formed(entry):
+    """What every configuration file holds, whatever its module."""
+    from bench import models
     cfg = load_json(entry["file"])
     assert cfg["name"] == entry["name"]
     assert sorted(cfg["reduced"]) == sorted(entry["reduced"])
-    mc = get_config(cfg["arch"])
-    assert cfg["hidden_size"] == mc.d_model
-    assert cfg["num_attention_heads"] == mc.num_heads
-    assert cfg["num_key_value_heads"] == mc.num_kv_heads
-    assert cfg.get("head_dim", mc.head_dim) == mc.head_dim
-    assert cfg["vocab_size"] == mc.vocab_size
-    assert cfg["family"] == mc.family
-    if mc.family == "moe":
-        assert cfg["num_local_experts"] == mc.num_experts
-        assert cfg["num_experts_per_tok"] == mc.experts_per_token
-        assert cfg["intermediate_size"] == mc.moe_d_ff
-    else:
-        assert cfg["intermediate_size"] == mc.d_ff
-    # depth: the runtime executes each distinct layer once
-    assert mc.num_layers == 32 and cfg["num_hidden_layers"] == 1
-    assert "decode_rel_err" in cfg["correct_limits"]
-    assert set(cfg["correct_limits"]) <= {"prefill_rel_err",
-                                          "decode_rel_err"}
     assert cfg["source"] == entry["source"]
     assert {"assumed", "deployment", "precision"} <= set(cfg)
+    model = models.load(cfg)
+    assert cfg["correct_limits"]
+    assert set(cfg["correct_limits"]) <= set(model.NUMBERS)
 
 
-@pytest.mark.parametrize("entry", BENCH["configs"], ids=lambda c: c["name"])
-def test_reference_stream_is_the_served_stream(entry):
+def test_a_config_without_a_model_is_refused():
+    from bench import models
+    cfg = load_json(BENCH["configs"][0]["file"])
+    for bad in (None, "", "gemm_stream.reference", "../x"):
+        broken = dict(cfg, model=bad)
+        if bad is None:
+            del broken["model"]
+        with pytest.raises(ValueError, match="names no module"):
+            models.load(broken)
+
+
+GEMM_STREAM = [c for c in BENCH["configs"]
+               if load_json(c["file"])["model"] == "gemm_stream"]
+
+
+@pytest.mark.parametrize("entry", GEMM_STREAM, ids=lambda c: c["name"])
+def test_config_widths_match_the_program(entry):
+    from bench.models import gemm_stream
+    cfg = load_json(entry["file"])
+    gemm_stream.validate(cfg)
+    # every run is held to a decode number (``required``)
+    assert any(gemm_stream.NUMBERS[k] == "decode"
+               for k in cfg["correct_limits"])
+    for key, value in (("hidden_size", cfg["hidden_size"] + 8),
+                       ("family", "ssm"), ("num_hidden_layers", 4)):
+        with pytest.raises(ValueError, match=key):
+            gemm_stream.validate(dict(cfg, **{key: value}))
+
+
+@pytest.mark.parametrize("entry", GEMM_STREAM, ids=lambda c: c["name"])
+def test_reference_stream_is_the_served_stream(entry, monkeypatch):
     """The reference's own stream has the program's shapes, names and
     draw order at both passes (built on the CPU, nothing runs)."""
-    from repro.configs.base import ShapeConfig
-    from repro.configs.feather import feather_config
-    from repro.core.model_gemms import gemm_workloads
-    from repro.configs.registry import get_config
-
-    from bench import reference
+    from bench.models import gemm_stream
+    from bench.models.gemm_stream import reference
     cfg = load_json(entry["file"])
-    mc = get_config(cfg["arch"])
-    for kind, seq in (("prefill", cfg["prefill_chunk"]), ("decode", 512)):
-        ops = gemm_workloads(mc, ShapeConfig("x", seq, 1, kind))
-        steps = reference.stream(cfg, kind, seq)
-        assert [(o.gemm.name, o.gemm.m, o.gemm.k, o.gemm.n, o.chained,
-                 o.dynamic) for o in ops] == \
-            [(s.name, s.m, s.k, s.n, s.chained, s.dynamic) for s in steps]
-    assert feather_config(4, 16) is not None
+    gemm_stream.validate(cfg)
+    stream = reference.stream
+    monkeypatch.setattr(reference, "stream",
+                        lambda *a: stream(*a)[:-1])
+    with pytest.raises(ValueError, match="stream"):
+        gemm_stream.validate(cfg)
 
 
 def test_every_cell_has_a_config_and_mix_on_disk():

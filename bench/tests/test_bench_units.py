@@ -77,7 +77,9 @@ def test_no_module_loads_a_backend_at_import():
     code = (
         "import sys; sys.path[:0] = ['.', 'src']\n"
         "import pkgutil, importlib, bench, bench.metrics, bench.kernels\n"
-        "for pkg in (bench, bench.metrics, bench.kernels):\n"
+        "import bench.models, bench.models.gemm_stream\n"
+        "for pkg in (bench, bench.metrics, bench.kernels, bench.models,\n"
+        "            bench.models.gemm_stream):\n"
         "    for m in pkgutil.iter_modules(pkg.__path__):\n"
         "        if m.name not in ('tests', 'run', 'control'):\n"
         "            importlib.import_module(pkg.__name__ + '.' + m.name)\n"
